@@ -46,8 +46,8 @@ Reproductions:
    HARD token-identity assert (serving/README.md "Sharded serving"),
    plus decode tokens/s and per-device KV bytes rows (the head-sharded
    paged pool halves per-device KV at TP=2).  Needs two devices; run as
-   a CLI the module forces two XLA host devices before jax loads, so
-   the rows are live even on a one-CPU CI runner.
+   a CLI with JAX_PLATFORMS=cpu the module forces two XLA host devices
+   before jax loads, so the rows are live even on a one-CPU CI runner.
 
 9. kv-quant A/B: the same greedy mix through a bf16-KV and an int8-KV
    paged engine at a fixed pool_tokens budget.  The int8 pool carries
@@ -72,13 +72,15 @@ import os
 import sys
 from typing import List, Optional, Tuple
 
-# mix 8 needs >= 2 devices; on the usual 1-CPU runner force two XLA host
-# devices — must happen before jax's first import (harmless for every
-# other mix: their engines are mesh-free and compile single-device
-# modules on device 0).  When another module imported jax first (e.g. a
-# test importing this file) the flag is too late; sharded_rows then
-# degrades to an explicit skip row instead of asserting.
-if "jax" not in sys.modules and "xla_force_host_platform_device_count" \
+# mix 8 needs >= 2 devices; on a CPU run (JAX_PLATFORMS=cpu set
+# explicitly) force two XLA host devices — must happen before jax's first
+# import (harmless for every other mix: their engines are mesh-free and
+# compile single-device modules on device 0).  When another module
+# imported jax first (e.g. a test importing this file) the flag is too
+# late; sharded_rows then degrades to an explicit skip row instead of
+# asserting.  Elsewhere the mix runs on the real devices or skips.
+if os.environ.get("JAX_PLATFORMS") == "cpu" and "jax" not in sys.modules \
+        and "xla_force_host_platform_device_count" \
         not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
@@ -89,13 +91,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, scaled_down
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
+from repro.obs.peaks import PEAKS
 from repro.serving.engine import InferenceEngine, Request
 from repro.serving.scheduler import SchedulerConfig
 
 # v5e-per-chip constants (same as launch.dryrun)
-HBM_BW = 819e9
-PEAK = 197e12
+HBM_BW = PEAKS["TPU v5 lite"]["hbm_bytes_per_s"]
+PEAK = PEAKS["TPU v5 lite"]["bf16_flops"]
 
 _STATE = {}
 
@@ -786,7 +790,7 @@ def sharded_rows(smoke: bool = False) -> List[str]:
         return [r.generated for r in reqs], s, eng.kv_stats()
 
     base, s1, kv1 = go(None)
-    tp, s2, kv2 = go(jax.make_mesh((2,), ("model",)))
+    tp, s2, kv2 = go(make_mesh((2,), ("model",)))
     identical = int(base == tp)
     rows = [
         f"serve_tp2_outputs_identical,{identical},"

@@ -106,6 +106,9 @@ def measured_rows() -> List[Tuple[str, float]]:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     env.pop("XLA_FLAGS", None)
+    # a host-device simulation by design: the child must not take an
+    # accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _MEASURE_SRC], env=env,
                          capture_output=True, text=True, timeout=900)
     rows = []

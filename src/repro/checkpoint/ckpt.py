@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro._compat import tree_flatten_with_path
-
 
 def _leaf_id(path) -> str:
     out = []
@@ -63,7 +61,7 @@ def save(root: str, step: int, tree, extra_meta: Optional[Dict] = None,
     if os.path.exists(tmp):
         shutil.rmtree(tmp)
     os.makedirs(tmp, exist_ok=True)
-    leaves, treedef = tree_flatten_with_path(tree)
+    leaves, treedef = jax.tree.flatten_with_path(tree)
     manifest: Dict[str, Any] = {
         "step": step, "time": time.time(),
         "treedef": jax.tree.unflatten(
@@ -150,7 +148,7 @@ def restore(root: str, target, step: Optional[int] = None,
     with open(os.path.join(step_dir, "manifest.json")) as f:
         manifest = json.load(f)
     by_id = {e["id"]: e for e in manifest["leaves"]}
-    leaves, treedef = tree_flatten_with_path(target)
+    leaves, treedef = jax.tree.flatten_with_path(target)
     shard_leaves = (jax.tree.leaves(shardings) if shardings is not None
                     else [None] * len(leaves))
     out = []

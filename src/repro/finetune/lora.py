@@ -14,7 +14,6 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro._compat import tree_flatten_with_path
 
 # default targets per mixer family; attention-specific entries are simply
 # absent in attention-free archs (see recipes.applicability)
@@ -43,7 +42,7 @@ def lora_init(params, lcfg: LoraConfig, key: jax.Array,
               dtype=jnp.float32):
     """Adapters {path_str: {"a": (..., din, r), "b": (..., r, dout)}}."""
     adapters = {}
-    leaves = tree_flatten_with_path(params)[0]
+    leaves = jax.tree.flatten_with_path(params)[0]
     keys = jax.random.split(key, max(len(leaves), 1))
     for (path, leaf), k in zip(leaves, keys):
         if _leaf_name(path) not in lcfg.targets or leaf.ndim < 2:
@@ -58,7 +57,7 @@ def lora_init(params, lcfg: LoraConfig, key: jax.Array,
 
 def lora_merge(params, adapters, lcfg: LoraConfig, dtype=None):
     """Materialize merged weights; non-target leaves pass through."""
-    flat = tree_flatten_with_path(params)
+    flat = jax.tree.flatten_with_path(params)
     out = []
     for path, leaf in flat[0]:
         ks = jax.tree_util.keystr(path)

@@ -15,6 +15,7 @@ from repro.configs.base import ModelConfig
 from repro.finetune.lora import LoraConfig, lora_merge
 from repro.models import model as M
 from repro.models.param import cast_tree
+from repro.obs.peaks import chip_peak
 from repro.training.optimizer import OptConfig, clip_by_global_norm, opt_update
 
 
@@ -45,7 +46,7 @@ def make_lora_sft_step(cfg: ModelConfig, opt_cfg: OptConfig,
 
 
 def instrument_sft_step(step_fn, cfg: ModelConfig, obs,
-                        peak_flops: float = 197e12,
+                        peak_flops: Optional[float] = None,
                         clock: Optional[Callable[[], float]] = None):
     """Wrap an SFT step with host-side observability: step-time
     histogram, token counters, throughput + estimated-MFU gauges, and
@@ -66,8 +67,12 @@ def instrument_sft_step(step_fn, cfg: ModelConfig, obs,
                            "SFT tokens consumed")
     g_tps = reg.gauge("repro_finetune_tokens_per_s",
                       "SFT throughput, last step")
-    g_mfu = reg.gauge("repro_finetune_mfu_ratio",
-                      "est. model FLOPs utilisation of the SFT step")
+    peak_flops = peak_flops or chip_peak(jax.devices()[0].device_kind,
+                                         "bf16_flops")
+    g_mfu = None
+    if peak_flops:
+        g_mfu = reg.gauge("repro_finetune_mfu_ratio",
+                          "est. model FLOPs utilisation of the SFT step")
     n_params = cfg.param_count(active_only=True)
     clk = clock if clock is not None else obs.clock
     state = {"step": 0}
@@ -87,7 +92,8 @@ def instrument_sft_step(step_fn, cfg: ModelConfig, obs,
         c_tokens.inc(n_tok)
         if wall > 0 and n_tok:
             g_tps.set(n_tok / wall)
-            g_mfu.set(6.0 * n_params * n_tok / (wall * peak_flops))
+            if g_mfu is not None:
+                g_mfu.set(6.0 * n_params * n_tok / (wall * peak_flops))
         return out
 
     return wrapped

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, da_ref, b_ref, c_ref, y_ref, hout_ref, h_scr, *,
                 Q: int):
@@ -99,7 +97,7 @@ def ssd_scan(xdt: jax.Array, dA: jax.Array, Bm: jax.Array, Cm: jax.Array,
             jax.ShapeDtypeStruct((BH, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xdt, dA[..., None], Bm, Cm)

@@ -1,9 +1,5 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
-# count on first backend initialization.
-
 import argparse
+import os
 import json
 import time
 import traceback
@@ -19,13 +15,14 @@ from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as M
 from repro.models.param import abstract_params, param_axes
+from repro.obs.peaks import PEAKS
 from repro.parallel import sharding as sh
 from repro.training.optimizer import OptConfig, opt_init, opt_state_axes
 from repro.training.train_step import make_train_step
 
-# --- TPU v5e-like target constants (per chip) ---
-PEAK_FLOPS = 197e12      # bf16
-HBM_BW = 819e9           # bytes/s
+# --- TPU v5e target constants (per chip) ---
+PEAK_FLOPS = PEAKS["TPU v5 lite"]["bf16_flops"]
+HBM_BW = PEAKS["TPU v5 lite"]["hbm_bytes_per_s"]
 ICI_BW = 50e9            # bytes/s per link (spec-conservative single link)
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__),
@@ -273,4 +270,10 @@ def main():
 
 
 if __name__ == "__main__":
+    # 512 host devices stand in for the production pod; XLA reads the
+    # flag when the backend starts, so it is set before the first device
+    # query and only when run as a script
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
+                               "--xla_force_host_platform_device_count=512"
+                               ).strip()
     main()

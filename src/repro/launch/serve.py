@@ -3,39 +3,28 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-4b \
         --requests 8 --max-tokens 16
 
+``--scale full`` serves the architecture at its published widths with
+bf16 weights (qwen1.5-4b is 15.8 GB in f32, more than one 16 GB TPU v5e
+chip holds); ``--scale tiny`` serves the scaled-down config in f32.
+
 ``--tp N`` serves one *sharded* replica (tensor parallelism over a
 ``("model",)`` mesh, serving/README.md "Sharded serving"); the gateway
-still sees exactly one endpoint.  On a single-CPU host the driver
-forces N XLA host devices so the flag is demoable anywhere.
+still sees exactly one endpoint.  With ``JAX_PLATFORMS=cpu`` set, serve
+forces N XLA host devices so the flag runs on a CPU host; on an
+accelerator it needs N real devices.
 
-Restores weights from ``--ckpt-dir`` if present (e.g. from
-``repro.launch.train``), otherwise serves random-init weights.
+Restores weights from ``--ckpt-dir`` when given (e.g. from
+``repro.launch.train``) and fails if it cannot; otherwise serves random
+weights drawn from a fixed seed.
+
+``main(argv)`` returns the run's summary (per-request tokens and TTFT,
+engine metrics, KV stats), so a caller in the same process can check it.
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import os
-import sys
-
-
-def _early_tp_flag():
-    """``--tp N`` on a host with fewer than N devices: force XLA host
-    devices.  Must run before jax's first import — XLA reads the flag
-    once at backend init, so it cannot live in main()."""
-    if "jax" in sys.modules:        # too late; make_mesh will error out
-        return
-    try:
-        n = int(sys.argv[sys.argv.index("--tp") + 1])
-    except (ValueError, IndexError):
-        return
-    if n > 1 and "xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={n}").strip()
-
-
-_early_tp_flag()
 
 import jax
 import jax.numpy as jnp
@@ -43,16 +32,52 @@ import numpy as np
 
 from repro.configs import get_config, scaled_down
 from repro.core.gateway import Gateway, GatewayError, ModelEntry
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
+from repro.parallel import sharding
 from repro.serving.engine import InferenceEngine, Request
 
 
-def main():
+def load_params(cfg, scale: str, key, mesh=None, ckpt_dir: str = ""):
+    """Serving weights: ``--scale tiny`` in f32, ``--scale full`` in
+    bf16.  Full-width weights are generated (or restored) already
+    sharded over ``mesh`` under the serving rules, so no device ever
+    holds the whole model (apertus-8b in bf16 is 16.1 GB).  An explicit
+    ``ckpt_dir`` that cannot be restored raises."""
+    dtype = jnp.float32 if scale == "tiny" else jnp.bfloat16
+    init = functools.partial(M.init, cfg, dtype=dtype)
+    shardings = None if mesh is None else sharding.tree_shardings(
+        M.model_param_axes(cfg), mesh, sharding.make_rules("serving_tp"))
+    if ckpt_dir:
+        from repro.checkpoint import ckpt as C
+        state, manifest = C.restore(
+            ckpt_dir, {"params": jax.eval_shape(init, key)},
+            shardings=None if shardings is None else {"params": shardings})
+        print(f"restored weights from {ckpt_dir} step {manifest['step']}")
+        return jax.tree.map(lambda x: x.astype(dtype), state["params"])
+    if scale == "tiny":
+        return init(key)
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
+def make_prompt(vocab_size: int, n: int):
+    """A prompt of ``n`` token ids drawn from a generator seeded by
+    ``n``: prompts of equal length are equal, so a repeated length in
+    ``--prompt-lens`` repeats a prompt."""
+    rng = np.random.default_rng(n)
+    return [int(x) for x in rng.integers(1, vocab_size - 1, n)]
+
+
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--scale", default="tiny", choices=["tiny", "full"])
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--requests", type=int, default=4)
+    ap.add_argument("--prompt-lens", default="4,5,6,7,8",
+                    help="comma-separated prompt lengths, cycled over "
+                         "the requests; equal lengths give equal prompts")
     ap.add_argument("--max-tokens", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -73,8 +98,8 @@ def main():
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel degree of this replica (one "
                          "sharded engine = one gateway endpoint); KV "
-                         "heads must divide N; forces N XLA host "
-                         "devices on a single-device machine")
+                         "heads must divide N; with JAX_PLATFORMS=cpu "
+                         "forces N XLA host devices")
     ap.add_argument("--adapters", type=int, default=0,
                     help="serve N demo LoRA adapters (tenant0..N-1) from "
                          "one adapter pool; requests round-robin across "
@@ -136,25 +161,32 @@ def main():
                     help="with --metrics-out: also re-dump the snapshot "
                          "every N requests (a cheap stand-in for a "
                          "scrape endpoint)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
+    flags = os.environ.get("XLA_FLAGS", "")
+    if (args.tp > 1 and os.environ.get("JAX_PLATFORMS") == "cpu"
+            and "xla_force_host_platform_device_count" not in flags):
+        # a CPU run stands N host devices in for the mesh; XLA reads the
+        # flag when the backend starts, so a process that has queried
+        # its devices already gets the device-count error below
+        os.environ["XLA_FLAGS"] = (
+            flags + f" --xla_force_host_platform_device_count={args.tp}"
+        ).strip()
+    mesh = None
+    if args.tp > 1:
+        if jax.device_count() < args.tp:
+            ap.error(f"--tp {args.tp} needs {args.tp} devices, have "
+                     f"{jax.device_count()}")
+        mesh = make_mesh((args.tp,), ("model",),
+                         devices=jax.devices()[:args.tp])
+        print(f"tensor parallel: TP={args.tp} over "
+              f"{[d.platform + str(d.id) for d in mesh.devices.flat]}")
 
     cfg = get_config(args.arch)
     if args.scale == "tiny":
         cfg = scaled_down(cfg)
-    params = M.init(cfg, jax.random.PRNGKey(0), jnp.float32)
-    if args.ckpt_dir:
-        from repro.checkpoint import ckpt as C
-        try:
-            state, manifest = C.restore(args.ckpt_dir,
-                                        {"params": params, "opt": None})
-        except Exception:
-            target = {"params": params}
-            try:
-                state, manifest = C.restore(args.ckpt_dir, target)
-                params = state["params"]
-                print(f"restored weights from step {manifest['step']}")
-            except Exception as e:  # noqa: BLE001
-                print(f"no usable checkpoint ({e}); serving random init")
+    params = load_params(cfg, args.scale, jax.random.PRNGKey(0), mesh,
+                         args.ckpt_dir)
 
     if args.role == "decode" and not args.disagg:
         ap.error("--role decode has no handoff source in a single-engine "
@@ -176,30 +208,13 @@ def main():
         draft_cfg = get_config(args.draft_config)
         if args.scale == "tiny":
             draft_cfg = scaled_down(draft_cfg)
-        draft_params = M.init(draft_cfg, jax.random.PRNGKey(1),
-                              jnp.float32)
-        if args.draft_ckpt_dir:
-            from repro.checkpoint import ckpt as C
-            try:
-                state, mani = C.restore(args.draft_ckpt_dir,
-                                        {"params": draft_params})
-                draft_params = state["params"]
-                print(f"draft weights from step {mani['step']}")
-            except Exception as e:  # noqa: BLE001
-                print(f"no usable draft checkpoint ({e}); random init")
+        draft_params = load_params(draft_cfg, args.scale,
+                                   jax.random.PRNGKey(1), None,
+                                   args.draft_ckpt_dir)
     obs = None
     if args.metrics_out or args.trace_out:
         from repro.obs import Observability
         obs = Observability()
-    mesh = None
-    if args.tp > 1:
-        if jax.device_count() < args.tp:
-            ap.error(f"--tp {args.tp} needs {args.tp} devices, have "
-                     f"{jax.device_count()} (is jax imported before "
-                     f"repro.launch.serve?)")
-        mesh = jax.make_mesh((args.tp,), ("model",))
-        print(f"tensor parallel: TP={args.tp} over "
-              f"{[d.platform + str(d.id) for d in mesh.devices.flat]}")
     def mk_engine(name="engine", role="unified", spec=True):
         # speculative decoding only makes sense where tokens are
         # emitted, so a prefill-role engine never carries a drafter
@@ -240,14 +255,15 @@ def main():
                 publish_adapter(pre, f"tenant{i}", ad, lcfg)
             names.append(f"{cfg.name}@tenant{i}")
 
+    lens = [int(x) for x in args.prompt_lens.split(",")]
+    prompts = [make_prompt(cfg.vocab_size, lens[i % len(lens)])
+               for i in range(args.requests)]
+
     if args.role == "prefill" and not args.disagg:
         # one side of a disaggregated deployment: run the prompts to
         # handoff export and report the outbox (no decode peer in this
         # process — --disagg serves the full pair)
-        rng = np.random.default_rng(0)
-        for i in range(args.requests):
-            prompt = [int(x) for x in rng.integers(1, cfg.vocab_size - 1,
-                                                   4 + i % 5)]
+        for prompt in prompts:
             eng.submit(Request(prompt=prompt,
                                max_new_tokens=args.max_tokens,
                                temperature=args.temperature))
@@ -264,7 +280,7 @@ def main():
                 print(f"metrics snapshot -> {args.metrics_out}")
             if args.trace_out:
                 obs.write_trace(args.trace_out)
-        return
+        return {"metrics": s, "requests": [], "kv": eng.kv_stats()}
     endpoint = eng
     if args.chaos:
         from repro.serving.faults import (ChaosEngine, FaultInjector,
@@ -290,10 +306,8 @@ def main():
         gw.collect_metrics()          # pull engine/pool/cache state
         obs.write_metrics(args.metrics_out)
 
-    rng = np.random.default_rng(0)
-    for i in range(args.requests):
-        prompt = [int(x) for x in rng.integers(1, cfg.vocab_size - 1,
-                                               4 + i % 5)]
+    results = []
+    for i, prompt in enumerate(prompts):
         model = names[i % len(names)]
         try:
             out = gw.completion(api_key=key.key, model=model,
@@ -305,8 +319,15 @@ def main():
             # crash of the driver
             print(f"req{i}: model={model} FAILED "
                   f"{type(e).__name__}: {e}")
+            results.append({"model": model, "prompt": prompt,
+                            "error": f"{type(e).__name__}: {e}"})
             continue
-        print(f"req{i}: model={model} prompt={prompt} -> {out['tokens']}")
+        rec = eng.metrics.requests.get(out["id"])
+        ttft = rec.ttft if rec is not None else None
+        results.append({"model": model, "prompt": prompt,
+                        "tokens": list(out["tokens"]), "ttft_s": ttft})
+        print(f"req{i}: model={model} prompt_len={len(prompt)} "
+              f"ttft_s={ttft} -> {out['tokens']}")
         if args.snapshot_every and (i + 1) % args.snapshot_every == 0:
             dump_snapshot()
     s = eng.metrics.summary()
@@ -345,6 +366,7 @@ def main():
             obs.write_trace(args.trace_out)
             print(f"perfetto trace -> {args.trace_out} "
                   f"(open at https://ui.perfetto.dev)")
+    return {"metrics": s, "requests": results, "kv": eng.kv_stats()}
 
 
 if __name__ == "__main__":

@@ -13,12 +13,13 @@ import shutil
 
 from repro.configs import get_config, scaled_down
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.training.optimizer import OptConfig
 from repro.training.schedule import SCHEDULES
 from repro.training.trainer import Trainer, TrainerConfig
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--scale", default="tiny", choices=["tiny", "full"])
@@ -39,7 +40,8 @@ def main():
     ap.add_argument("--trace-out", default="",
                     help="write a Perfetto trace_event JSON of the "
                          "step/checkpoint/failure timeline here")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.scale == "tiny":

@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro._compat import tree_flatten_with_path
 from repro.configs.base import ModelConfig
 from repro.finetune.lora import DEFAULT_TARGETS, LoraConfig, lora_unflatten
 
@@ -119,7 +118,7 @@ class AdapterPool:
         # target map: path tuple -> dict(kaxis, a_shape, b_shape) where
         # shapes are the *padded* per-adapter shapes (no K axis)
         self._targets: Dict[Tuple, Dict[str, Any]] = {}
-        for path, leaf in tree_flatten_with_path(params)[0]:
+        for path, leaf in jax.tree.flatten_with_path(params)[0]:
             pt = _path_tuple(path)
             if pt[-1] not in self.targets_allowed or leaf.ndim < 2:
                 continue

@@ -27,6 +27,7 @@ from repro.checkpoint.async_ckpt import AsyncCheckpointer
 from repro.configs.base import ModelConfig
 from repro.models import model as M
 from repro.models.param import abstract_params, param_axes
+from repro.obs.peaks import chip_peak
 from repro.parallel import sharding as sh
 from repro.training.optimizer import OptConfig, opt_init, opt_state_axes
 from repro.training.train_step import make_train_step
@@ -83,7 +84,7 @@ class Trainer:
                  node_timer: Optional[Callable[[int], Dict[str, float]]] = None,
                  on_straggler: Optional[Callable[[str], None]] = None,
                  param_dtype=jnp.float32, obs=None,
-                 peak_flops: float = 197e12):
+                 peak_flops: Optional[float] = None):
         self.cfg, self.opt_cfg, self.tc = cfg, opt_cfg, tc
         self.data = data
         self.schedule_fn = schedule_fn
@@ -103,7 +104,10 @@ class Trainer:
         # wall time exactly as the pre-existing `wall` log field did, so
         # attaching obs adds no device syncs to the step loop.
         self.obs = obs
-        self.peak_flops = peak_flops
+        # MFU needs the chip's published peak; an unknown device kind
+        # (the CPU among them) reports no MFU
+        self.peak_flops = peak_flops or chip_peak(
+            jax.devices()[0].device_kind, "bf16_flops")
         try:
             self._n_active = cfg.param_count(active_only=True)
         except TypeError:
@@ -132,9 +136,12 @@ class Trainer:
             self._g_tps = reg.gauge(
                 "repro_train_tokens_per_s",
                 "training throughput, last step")
-            self._g_mfu = reg.gauge(
-                "repro_train_mfu_ratio",
-                "est. model FLOPs utilisation (6*N*tokens / wall*peak)")
+            self._g_mfu = None
+            if self.peak_flops:
+                self._g_mfu = reg.gauge(
+                    "repro_train_mfu_ratio",
+                    "est. model FLOPs utilisation (6*N*tokens / "
+                    "wall*peak)")
         self._build(mesh, rules)
         key = jax.random.PRNGKey(seed)
         self.params = M.init(cfg, key, param_dtype)
@@ -270,8 +277,9 @@ class Trainer:
                 self._c_tokens.inc(n_tok)
                 if wall > 0:
                     self._g_tps.set(n_tok / wall)
-                    self._g_mfu.set(6.0 * self._n_active * n_tok
-                                    / (wall * self.peak_flops))
+                    if self._g_mfu is not None:
+                        self._g_mfu.set(6.0 * self._n_active * n_tok
+                                        / (wall * self.peak_flops))
             if self.node_timer is not None:
                 for node in self.detector.observe(self.node_timer(self.step)):
                     if obs is not None:

@@ -2,17 +2,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.parallel.compression import (compress_with_feedback, ef_init,
                                         wire_bytes)
-
-# jax < 0.5 (e.g. the 0.4.37 container pin) emits different HLO text /
-# lacks the new shard_map spelling; see README "Known
-# jax-version-dependent failures".  strict=False: current-jax CI still
-# runs (and must pass) these.
-OLD_JAX = tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5)
 
 
 def test_int8_roundtrip_bounded_error():
@@ -46,9 +39,6 @@ def test_wire_bytes_shrink(bits, n):
     assert wire_bytes(g, bits) < n * 4 + 8
 
 
-@pytest.mark.xfail(OLD_JAX, reason="jax<0.5: reduction-schedule HLO "
-                   "text differs (README: known version failures)",
-                   strict=False)
 def test_reduction_schedules_agree():
     """All three schedules produce the same reduced gradients."""
     import os, subprocess, sys

@@ -151,6 +151,17 @@ def _slots(pool_blocks=12, block_size=4):
                            pool_tokens=pool_blocks * block_size)
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _pool_shapes_allocated_once():
+    """Allocate every pool shape the property tests use before their
+    first example: the first allocation of a shape compiles, a one-time
+    cost that hypothesis's per-example deadline would charge to whichever
+    example happens to run first."""
+    _slots()
+    _slots(pool_blocks=4, block_size=4)
+    _slots8()
+
+
 @settings(max_examples=15)
 @given(grow_to=st.integers(min_value=1, max_value=32),
        trim_to=st.integers(min_value=1, max_value=32))

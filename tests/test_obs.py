@@ -316,7 +316,7 @@ def test_trainer_emits_step_and_mfu_series(tiny_cfg, tmp_path):
     tr = Trainer(tiny_cfg, OptConfig(lr=1e-3), data,
                  TrainerConfig(num_steps=3, ckpt_every=100,
                                ckpt_dir=str(tmp_path), log_every=1),
-                 obs=obs)
+                 obs=obs, peak_flops=1e12)
     tr.run()
     snap = obs.registry.snapshot()
     assert snap["repro_train_steps_total"] == 3
@@ -329,3 +329,27 @@ def test_trainer_emits_step_and_mfu_series(tiny_cfg, tmp_path):
     assert len(steps) == 3
     text = obs.registry.to_prometheus()
     assert "repro_train_mfu_ratio" in text
+
+
+def test_mfu_only_for_a_device_kind_with_a_published_peak():
+    """The peak table is keyed by device kind; a kind it lacks (the CPU
+    here) gets no MFU gauge rather than another chip's figure."""
+    import jax
+    from repro.finetune.sft import instrument_sft_step
+    from repro.obs.peaks import chip_peak
+    assert chip_peak("TPU v5 lite", "bf16_flops") == 197e12
+    assert chip_peak("cpu", "bf16_flops") is None
+    kind = jax.devices()[0].device_kind
+    obs = Observability()
+    step = instrument_sft_step(lambda p, o, b: (p, o, {}),
+                               _TinyCfg(), obs)
+    step(None, None, {"tokens": jax.numpy.zeros((2, 8))})
+    snap = obs.registry.snapshot()
+    assert snap["repro_finetune_tokens_total"] == 16
+    assert ("repro_finetune_mfu_ratio" in snap) == (
+        chip_peak(kind, "bf16_flops") is not None)
+
+
+class _TinyCfg:
+    def param_count(self, active_only=False):
+        return 1000

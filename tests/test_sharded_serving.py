@@ -31,6 +31,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, scaled_down
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.parallel import sharding
 from repro.serving.engine import InferenceEngine, Request
@@ -54,7 +55,7 @@ def _mla_cfg():
 
 
 def _tp_mesh(n: int):
-    return jax.make_mesh((n,), ("model",))
+    return make_mesh((n,), ("model",))
 
 
 def _prompts(vocab: int, n: int = 5, seed: int = 0):

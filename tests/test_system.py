@@ -135,10 +135,6 @@ def test_full_lifecycle(tmp_path, tiny_cfg):
     assert "dataset" in lineage_kinds and "checkpoint" in lineage_kinds
 
 
-@pytest.mark.xfail(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="jax<0.5: no jax.shard_map / fake-device flag spelling "
-           "differs (README: known version failures)", strict=False)
 def test_small_mesh_dryrun_subprocess():
     """A reduced MoE config must lower+compile on a fake 2x2 mesh with the
     production sharding rules — validates the dry-run machinery itself
@@ -153,12 +149,13 @@ from repro.models import model as M
 from repro.models.param import abstract_params, param_axes
 from repro.parallel import sharding as sh
 from repro.launch import hlo_analysis
+from repro.launch.mesh import make_mesh
 from repro.training.optimizer import OptConfig, opt_init, opt_state_axes
 from repro.training.train_step import make_train_step
 
 cfg = scaled_down(get_config("granite-moe-3b-a800m"),
                   num_experts=8, moe_top_k=2, vocab_size=512)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 rules = sh.make_rules("train")
 shape = ShapeSpec("tiny_train", 64, 8, "train")
 axes = param_axes(M.model_specs(cfg))
@@ -186,3 +183,39 @@ print("SMALL-MESH-DRYRUN-OK", int(res["flops"]),
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=900)
     assert "SMALL-MESH-DRYRUN-OK" in out.stdout, out.stderr[-3000:]
+
+
+def test_serve_main_returns_the_served_tokens(tmp_path, monkeypatch):
+    """``serve.main(argv)`` is the entry point ``chip_smoke.py`` drives:
+    it returns every request's tokens, and a repeated prompt (equal
+    lengths give equal prompts) repeats its greedy tokens."""
+    from repro.launch import serve
+    # with the variable set the cache helper leaves jax's config alone,
+    # so this test changes nothing for the tests after it
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    out = serve.main(["--arch", "qwen1.5-4b", "--requests", "3",
+                      "--prompt-lens", "12,5,12", "--max-tokens", "4"])
+    reqs = out["requests"]
+    assert [len(r["prompt"]) for r in reqs] == [12, 5, 12]
+    assert all(len(r["tokens"]) == 4 for r in reqs)
+    assert reqs[0]["prompt"] == reqs[2]["prompt"]
+    assert reqs[0]["tokens"] == reqs[2]["tokens"]
+    assert out["metrics"]["completed"] == 3
+
+
+def test_serve_main_restores_or_fails_on_an_explicit_checkpoint(tmp_path):
+    """An explicit ``--ckpt-dir`` is restored, or serving fails: it
+    never falls back to random weights."""
+    from repro.checkpoint import ckpt as C
+    from repro.launch import serve
+    cfg = scaled_down(get_config("qwen1.5-4b"))
+    with pytest.raises(FileNotFoundError):
+        serve.load_params(cfg, "tiny", jax.random.PRNGKey(0),
+                          ckpt_dir=str(tmp_path / "missing"))
+    saved = M.init(cfg, jax.random.PRNGKey(7))
+    C.save(str(tmp_path / "ck"), 3, {"params": saved})
+    got = serve.load_params(cfg, "tiny", jax.random.PRNGKey(0),
+                            ckpt_dir=str(tmp_path / "ck"))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(saved)):
+        assert a.dtype == jnp.float32
+        assert jnp.array_equal(a, b)
