@@ -270,6 +270,16 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths):
     return decode_attention(q, k_seq, v_seq, lengths)
 
 
+def paged_kv_read_tokens(batch: int, table_width: int,
+                         block_size: int) -> int:
+    """KV positions one layer's paged attention reads per step:
+    :func:`paged_decode_attention` (and the verify, int8 and latent
+    variants) gathers every row's whole block table, ``batch x
+    table_width x block_size`` positions, whatever the live lengths are.
+    Keep it beside the gather so that it changes when the gather does."""
+    return batch * table_width * block_size
+
+
 def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths):
     """Multi-token tail attention against a *paged* cache (jnp oracle).
 
@@ -401,16 +411,18 @@ def attention_block(cfg: ModelConfig, p, x, positions, *,
     if mode in ("train", "prefill"):
         q, k, v = project_qkv(cfg, p, x, positions,
                               lora=lora, adapter_ids=adapter_ids)
-        if cfg.attn_impl == "naive":
-            o = naive_attention(q, k, v, causal=causal,
-                                kv_valid_len=kv_valid_len)
-        else:
-            o = blockwise_attention(q, k, v, causal=causal,
+        with jax.named_scope("attn"):
+            if cfg.attn_impl == "naive":
+                o = naive_attention(q, k, v, causal=causal,
                                     kv_valid_len=kv_valid_len)
+            else:
+                o = blockwise_attention(q, k, v, causal=causal,
+                                        kv_valid_len=kv_valid_len)
         o = sharding.constrain(o, ("act_batch", "act_qseq", "act_heads", None))
         new_cache = None
         if mode == "prefill":
-            new_cache = {"k": k.astype(dt), "v": v.astype(dt)}
+            with jax.named_scope("kv_write"):
+                new_cache = {"k": k.astype(dt), "v": v.astype(dt)}
     elif block_tables is not None and "k_scale" in cache:
         # int8 pool: symmetric per-block-per-head scales, quantized at
         # write time (running-max block scale, see
@@ -422,27 +434,29 @@ def attention_block(cfg: ModelConfig, p, x, positions, *,
         blk = cache["k"].shape[1]
         k_pool, v_pool = cache["k"], cache["v"]
         k_sc, v_sc = cache["k_scale"], cache["v_scale"]
-        for t in range(S):
-            idx = lengths - S + t
-            pb = jnp.take_along_axis(block_tables, (idx // blk)[:, None],
-                                     axis=1)[:, 0]
-            off = idx % blk
-            k_pool, k_sc = quantized_scatter_token(k_pool, k_sc,
-                                                   k[:, t], pb, off)
-            v_pool, v_sc = quantized_scatter_token(v_pool, v_sc,
-                                                   v[:, t], pb, off)
-        k_pool = sharding.constrain(
-            k_pool, ("act_batch", "act_kvseq", "act_heads", None))
-        v_pool = sharding.constrain(
-            v_pool, ("act_batch", "act_kvseq", "act_heads", None))
-        k_sc = sharding.constrain(k_sc, ("act_batch", "act_heads"))
-        v_sc = sharding.constrain(v_sc, ("act_batch", "act_heads"))
-        if S == 1:
-            o = paged_decode_attention_int8(q, k_pool, v_pool, k_sc, v_sc,
-                                            block_tables, lengths)
-        else:
-            o = paged_verify_attention_int8(q, k_pool, v_pool, k_sc, v_sc,
-                                            block_tables, lengths)
+        with jax.named_scope("kv_write"):
+            for t in range(S):
+                idx = lengths - S + t
+                pb = jnp.take_along_axis(block_tables,
+                                         (idx // blk)[:, None], axis=1)[:, 0]
+                off = idx % blk
+                k_pool, k_sc = quantized_scatter_token(k_pool, k_sc,
+                                                       k[:, t], pb, off)
+                v_pool, v_sc = quantized_scatter_token(v_pool, v_sc,
+                                                       v[:, t], pb, off)
+            k_pool = sharding.constrain(
+                k_pool, ("act_batch", "act_kvseq", "act_heads", None))
+            v_pool = sharding.constrain(
+                v_pool, ("act_batch", "act_kvseq", "act_heads", None))
+            k_sc = sharding.constrain(k_sc, ("act_batch", "act_heads"))
+            v_sc = sharding.constrain(v_sc, ("act_batch", "act_heads"))
+        with jax.named_scope("attn"):
+            if S == 1:
+                o = paged_decode_attention_int8(q, k_pool, v_pool, k_sc,
+                                                v_sc, block_tables, lengths)
+            else:
+                o = paged_verify_attention_int8(q, k_pool, v_pool, k_sc,
+                                                v_sc, block_tables, lengths)
         new_cache = {"k": k_pool, "v": v_pool,
                      "k_scale": k_sc, "v_scale": v_sc}
     elif block_tables is not None:
@@ -456,47 +470,56 @@ def attention_block(cfg: ModelConfig, p, x, positions, *,
         # physical block separately — S is a static jit constant).  Inert
         # rows have lengths == 1, so their (clamped-negative) positions
         # resolve to table column 0 == the reserved null block.
-        for t in range(S):
-            idx = lengths - S + t
-            pb = jnp.take_along_axis(block_tables, (idx // blk)[:, None],
-                                     axis=1)[:, 0]
-            off = idx % blk
-            k_cache = k_cache.at[pb, off].set(
-                k[:, t].astype(k_cache.dtype))
-            v_cache = v_cache.at[pb, off].set(
-                v[:, t].astype(v_cache.dtype))
-        # pool leaves are (num_blocks, block_size, KV, hd): the block and
-        # in-block dims sit in the (act_batch, act_kvseq) slots, which a
-        # serving rule set maps to None — so this pins exactly the head
-        # axis and the updated pool keeps the input pool's sharding
-        k_cache = sharding.constrain(
-            k_cache, ("act_batch", "act_kvseq", "act_heads", None))
-        v_cache = sharding.constrain(
-            v_cache, ("act_batch", "act_kvseq", "act_heads", None))
-        if S == 1:
-            o = paged_decode_attention(q, k_cache, v_cache, block_tables,
-                                       lengths)
-        else:
-            o = paged_verify_attention(q, k_cache, v_cache, block_tables,
-                                       lengths)
+        with jax.named_scope("kv_write"):
+            for t in range(S):
+                idx = lengths - S + t
+                pb = jnp.take_along_axis(block_tables,
+                                         (idx // blk)[:, None], axis=1)[:, 0]
+                off = idx % blk
+                k_cache = k_cache.at[pb, off].set(
+                    k[:, t].astype(k_cache.dtype))
+                v_cache = v_cache.at[pb, off].set(
+                    v[:, t].astype(v_cache.dtype))
+            # pool leaves are (num_blocks, block_size, KV, hd): the block
+            # and in-block dims sit in the (act_batch, act_kvseq) slots,
+            # which a serving rule set maps to None — so this pins
+            # exactly the head axis and the updated pool keeps the input
+            # pool's sharding
+            k_cache = sharding.constrain(
+                k_cache, ("act_batch", "act_kvseq", "act_heads", None))
+            v_cache = sharding.constrain(
+                v_cache, ("act_batch", "act_kvseq", "act_heads", None))
+        with jax.named_scope("attn"):
+            if S == 1:
+                o = paged_decode_attention(q, k_cache, v_cache,
+                                           block_tables, lengths)
+            else:
+                o = paged_verify_attention(q, k_cache, v_cache,
+                                           block_tables, lengths)
         new_cache = {"k": k_cache, "v": v_cache}
     else:
         q, k, v = project_qkv(cfg, p, x, positions,
                               lora=lora, adapter_ids=adapter_ids)
         S = q.shape[1]
         idx = lengths - S  # slot of the first (oldest) tail token
-        k_cache = jax.vmap(lambda c, kk, i: jax.lax.dynamic_update_slice(
-            c, kk, (i, 0, 0)))(cache["k"], k.astype(cache["k"].dtype), idx)
-        v_cache = jax.vmap(lambda c, vv, i: jax.lax.dynamic_update_slice(
-            c, vv, (i, 0, 0)))(cache["v"], v.astype(cache["v"].dtype), idx)
-        k_cache = sharding.constrain(
-            k_cache, ("act_batch", "act_kvseq", "act_heads", None))
-        v_cache = sharding.constrain(
-            v_cache, ("act_batch", "act_kvseq", "act_heads", None))
-        if S == 1:
-            o = decode_attention(q, k_cache, v_cache, lengths)
-        else:
-            o = verify_attention(q, k_cache, v_cache, lengths)
+        with jax.named_scope("kv_write"):
+            k_cache = jax.vmap(
+                lambda c, kk, i: jax.lax.dynamic_update_slice(
+                    c, kk, (i, 0, 0)))(cache["k"],
+                                       k.astype(cache["k"].dtype), idx)
+            v_cache = jax.vmap(
+                lambda c, vv, i: jax.lax.dynamic_update_slice(
+                    c, vv, (i, 0, 0)))(cache["v"],
+                                       v.astype(cache["v"].dtype), idx)
+            k_cache = sharding.constrain(
+                k_cache, ("act_batch", "act_kvseq", "act_heads", None))
+            v_cache = sharding.constrain(
+                v_cache, ("act_batch", "act_kvseq", "act_heads", None))
+        with jax.named_scope("attn"):
+            if S == 1:
+                o = decode_attention(q, k_cache, v_cache, lengths)
+            else:
+                o = verify_attention(q, k_cache, v_cache, lengths)
         new_cache = {"k": k_cache, "v": v_cache}
     o2 = o.reshape(B, o.shape[1], -1).astype(dt)
     out = jnp.einsum("bsq,qd->bsd", o2, p["wo"])

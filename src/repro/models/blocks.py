@@ -8,6 +8,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
@@ -142,13 +143,14 @@ def apply_layer(cfg: ModelConfig, p, x, positions, *, mixer: str, ffn: str,
             kv = cross_cache
         x = x + attn_mod.cross_attention_block(cfg, p["cross"], h, kv)
 
-    if ffn == "mlp":
-        h = apply_norm(cfg, p["ln2"], x)
-        x = x + apply_mlp(cfg, p["mlp"], h)
-    elif ffn == "moe":
-        h = apply_norm(cfg, p["ln2"], x)
-        y, aux = moe_mod.moe_block(cfg, p["moe"], h)
-        x = x + y
+    if ffn in ("mlp", "moe"):
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg, p["ln2"], x)
+            if ffn == "mlp":
+                x = x + apply_mlp(cfg, p["mlp"], h)
+            else:
+                y, aux = moe_mod.moe_block(cfg, p["moe"], h)
+                x = x + y
 
     x = sharding.constrain(x, ("act_batch", "act_qseq", None))
     return x, new_cache, new_cross, aux

@@ -506,7 +506,8 @@ def _backbone(cfg: ModelConfig, params, x, positions, *, mode,
         elif mode == "decode":
             new_cache["cross"] = cache["cross"]  # carry through unchanged
 
-    x = apply_norm(cfg, params["ln_f"], x)
+    with jax.named_scope("head"):
+        x = apply_norm(cfg, params["ln_f"], x)
     return x, aux, (new_cache or None)
 
 
@@ -635,10 +636,12 @@ def prefill(cfg: ModelConfig, params, batch, *, lora=None,
         x, aux, cache = _backbone(cfg, params, x, pos, mode="prefill",
                                   lora=lora, adapter_ids=adapter_ids)
     # next-token logits at the last valid position of each sequence
-    idx = jnp.clip(lengths - 1, 0, x.shape[1] - 1)
-    x_last = jnp.take_along_axis(x, idx[:, None, None].astype(jnp.int32)
-                                 .repeat(x.shape[-1], -1), axis=1)[:, 0]
-    logits = unembed(cfg, params["embed"], x_last).astype(jnp.float32)
+    with jax.named_scope("head"):
+        idx = jnp.clip(lengths - 1, 0, x.shape[1] - 1)
+        x_last = jnp.take_along_axis(
+            x, idx[:, None, None].astype(jnp.int32).repeat(x.shape[-1], -1),
+            axis=1)[:, 0]
+        logits = unembed(cfg, params["embed"], x_last).astype(jnp.float32)
     return logits, cache, aux
 
 
@@ -653,7 +656,8 @@ def decode_step(cfg: ModelConfig, params, tokens, cache, lengths, *,
     x, _, new_cache = _backbone(cfg, params, x, pos, mode="decode",
                                 cache=cache, lengths=lengths,
                                 lora=lora, adapter_ids=adapter_ids)
-    logits = unembed(cfg, params["embed"], x[:, 0]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        logits = unembed(cfg, params["embed"], x[:, 0]).astype(jnp.float32)
     return logits, new_cache
 
 
@@ -674,7 +678,8 @@ def decode_step_paged(cfg: ModelConfig, params, tokens, pool, block_tables,
                                cache=pool, lengths=lengths,
                                block_tables=block_tables,
                                lora=lora, adapter_ids=adapter_ids)
-    logits = unembed(cfg, params["embed"], x[:, 0]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        logits = unembed(cfg, params["embed"], x[:, 0]).astype(jnp.float32)
     return logits, new_pool
 
 
@@ -701,7 +706,8 @@ def verify_step(cfg: ModelConfig, params, tokens, cache, lengths, *,
     x, _, new_cache = _backbone(cfg, params, x, pos, mode="decode",
                                 cache=cache, lengths=lengths,
                                 lora=lora, adapter_ids=adapter_ids)
-    logits = unembed(cfg, params["embed"], x).astype(jnp.float32)
+    with jax.named_scope("head"):
+        logits = unembed(cfg, params["embed"], x).astype(jnp.float32)
     return logits, new_cache
 
 
@@ -719,7 +725,8 @@ def verify_step_paged(cfg: ModelConfig, params, tokens, pool, block_tables,
                                cache=pool, lengths=lengths,
                                block_tables=block_tables,
                                lora=lora, adapter_ids=adapter_ids)
-    logits = unembed(cfg, params["embed"], x).astype(jnp.float32)
+    with jax.named_scope("head"):
+        logits = unembed(cfg, params["embed"], x).astype(jnp.float32)
     return logits, new_pool
 
 

@@ -12,14 +12,16 @@ lifecycle phase instruments against:
 
 Wiring: pass ``obs=Observability(clock=...)`` to
 ``serving.InferenceEngine``, ``core.Gateway``, or
-``training.Trainer`` (all default to ``obs=None`` — zero overhead when
-off).  Components *push* cheap events (span begin/end, histogram
+``training.Trainer`` (all default to ``obs=None``: nothing is kept in
+memory).  Components *push* cheap events (span begin/end, histogram
 observations) on their host-side paths and *pull* expensive state
 (pool occupancy, usage aggregates) via their ``collect_metrics``
-hooks at snapshot time.  Nothing here imports jax and nothing ever
-touches a device — instrumentation stays off the jit hot path by
-construction.  See docs/observability.md for the metric catalog and
-how to open a trace.
+hooks at snapshot time.  The scheduler's host phases are
+:class:`~repro.obs.tracer.Phase` annotations on the profiler's clock
+whether or not ``obs`` is attached.  Importing this package imports no
+jax, and nothing here touches a device — instrumentation stays off the
+jit hot path by construction.  See docs/observability.md for the metric
+catalog and how to open a trace.
 """
 from __future__ import annotations
 
@@ -28,9 +30,9 @@ from typing import Callable, Optional
 
 from repro.obs.registry import (DEFAULT_TIME_BUCKETS, MetricsRegistry,
                                 UNIT_SUFFIXES, validate_metric_name)
-from repro.obs.tracer import Span, Tracer
+from repro.obs.tracer import Phase, Span, Tracer
 
-__all__ = ["Observability", "MetricsRegistry", "Tracer", "Span",
+__all__ = ["Observability", "MetricsRegistry", "Tracer", "Span", "Phase",
            "validate_metric_name", "UNIT_SUFFIXES",
            "DEFAULT_TIME_BUCKETS"]
 

@@ -11,7 +11,14 @@ in https://ui.perfetto.dev or ``chrome://tracing``.
 Like ``serving/metrics.py``, the clock is injected: tests and the
 benchmark harness drive a virtual clock and get deterministic
 timestamps.  All bookkeeping is host-side Python (list appends); there
-is no jax import and no device sync anywhere near a jit boundary.
+is no device sync anywhere near a jit boundary.
+
+:class:`Phase` puts a host phase on the profiler's clock as well: it is
+always a ``jax.profiler`` annotation named ``repro.<track>.<name>``, so
+any profiler capture (xprof, TensorBoard, ``jax.profiler.start_trace``)
+shows it beside the device's operations, and it is a span of a
+:class:`Tracer` when one is given.  ``jax.profiler`` is imported when
+the first phase opens, so importing this module needs no jax.
 
 Track model: one Perfetto *thread* per track (``track()`` get-or-
 creates a tid and emits the ``thread_name`` metadata event).  Spans on
@@ -42,6 +49,47 @@ class Span:
         # (not copying) keeps the per-span cost at object construction
         self.args = args if args else {}
         self.closed = False
+
+
+class Phase:
+    """A host phase: an annotation ``repro.<track>.<name>`` on the
+    profiler's clock (a ``TraceMe``: about a microsecond when no
+    profiler session is active), and a span on ``tracer``'s track when
+    a tracer is given.  With ``step`` set it is a
+    ``StepTraceAnnotation`` carrying that step number.  Use it as a
+    context manager; :meth:`set` adds arguments before it closes (stats
+    on the profiler's event, ``args`` of the Perfetto span)."""
+    __slots__ = ("_ann", "_tracer", "_track", "_name", "_cat", "_args",
+                 "_span")
+
+    def __init__(self, tracer: Optional["Tracer"], track: str, name: str,
+                 cat: str = "", step: Optional[int] = None, **args):
+        from jax import profiler
+        full = f"repro.{track}.{name}"
+        self._ann = (profiler.TraceAnnotation(full, **args) if step is None
+                     else profiler.StepTraceAnnotation(full, step_num=step,
+                                                       **args))
+        self._tracer = tracer
+        self._track, self._name, self._cat = track, name, cat
+        self._args = args
+        self._span: Optional[Span] = None
+
+    def __enter__(self) -> "Phase":
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self._span = self._tracer.begin(self._track, self._name,
+                                            self._cat, **self._args)
+        return self
+
+    def set(self, **args):
+        self._ann.set_metadata(**args)
+        if self._span is not None:
+            self._span.args.update(args)
+
+    def __exit__(self, *exc):
+        if self._span is not None:
+            self._tracer.end(self._span)
+        self._ann.__exit__(*exc)
 
 
 class Tracer:
@@ -116,12 +164,6 @@ class Tracer:
         if args:
             ev["args"] = args
         self._emit(ev)
-
-    def counter(self, track: str, name: str, **values):
-        """A Perfetto counter sample (rendered as a track graph)."""
-        self._emit({"ph": "C", "name": name, "pid": 1,
-                    "tid": self.track(track),
-                    "ts": self._us(self.clock()), "args": dict(values)})
 
     # ------------------------------------------------------------ export
     @property

@@ -139,8 +139,9 @@ class InferenceEngine:
         ``obs`` (an :class:`repro.obs.Observability`, default off)
         turns on lifecycle observability: per-request trace spans and
         push-style latency histograms stream through a
-        :class:`TracingMetricsCollector`, the scheduler emits per-tick
-        spans and queue/occupancy gauges, and
+        :class:`TracingMetricsCollector`, the scheduler keeps its
+        per-tick spans (which are ``jax.profiler`` annotations with or
+        without ``obs``) and queue/occupancy gauges, and
         :meth:`collect_metrics` pulls KV-pool / prefix-cache /
         adapter-pool state into ``obs.registry`` on demand.  All
         instrumentation is host-side Python — nothing crosses the jit
@@ -286,18 +287,20 @@ class InferenceEngine:
             logits, nc = M.decode_step(cfg, p, t, c, l, lora=lo,
                                        adapter_ids=ai)
             nc = constrain_cache(nc, dense_axes)
-            if greedy:
-                return jnp.argmax(logits, -1).astype(jnp.int32), nc
-            return sample_batched(logits, key, temps, tks, tps), nc
+            with jax.named_scope("head"):
+                if greedy:
+                    return jnp.argmax(logits, -1).astype(jnp.int32), nc
+                return sample_batched(logits, key, temps, tks, tps), nc
 
         def _fused_paged(p, t, pool, bt, l, key, temps, tks, tps, lo, ai,
                          greedy):
             logits, np_ = M.decode_step_paged(cfg, p, t, pool, bt, l,
                                               lora=lo, adapter_ids=ai)
             np_ = constrain_cache(np_, cache_axes)
-            if greedy:
-                return jnp.argmax(logits, -1).astype(jnp.int32), np_
-            return sample_batched(logits, key, temps, tks, tps), np_
+            with jax.named_scope("head"):
+                if greedy:
+                    return jnp.argmax(logits, -1).astype(jnp.int32), np_
+                return sample_batched(logits, key, temps, tks, tps), np_
 
         self._decode_sample = mk_jit(_fused, static_argnums=(10,))
         self._decode_sample_paged = mk_jit(_fused_paged,

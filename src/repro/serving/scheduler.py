@@ -54,6 +54,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.attention import paged_kv_read_tokens
+from repro.obs.tracer import Phase
 from repro.serving.adapters import adapter_namespace
 from repro.serving.prefix_cache import (Match, PagedPrefixCache, PrefixCache,
                                         supports_prefix_cache)
@@ -141,12 +143,28 @@ class ChunkedPrefillScheduler:
         self._tick_pressure = 0   # pressure events in the current tick
         self._pressure = 0        # accumulated since last transition
         self._calm_ticks = 0      # consecutive pressure-free ticks
-        # observability (engine-owned; None = zero-overhead off state).
-        # Push-side instruments are pre-registered here so the per-tick
-        # path is attribute lookups + appends, never registry lookups.
+        # observability (engine-owned).  Every host phase is a profiler
+        # annotation (``Phase``); with ``obs`` attached it is also a span
+        # of ``obs.tracer`` and the push-side instruments below, which are
+        # pre-registered so the per-tick path is attribute lookups +
+        # appends, never registry lookups.
         self.obs = getattr(engine, "obs", None)
+        self._tracer = self.obs.tracer if self.obs is not None else None
         if self.obs is not None:
             reg = self.obs.registry
+            if self.paged:
+                pool = engine.slots.pool
+                # bytes of one KV position across all layers (scales
+                # included on an int8 pool)
+                self._kv_token_bytes = sum(
+                    leaf.nbytes for leaf in jax.tree.leaves(pool)) // (
+                    engine.slots.bp.num_blocks * engine.slots.block_size)
+                self._c_kv_read = reg.counter(
+                    "repro_kv_read_bytes_total",
+                    "KV bytes the paged steps' attention read")
+                self._c_kv_live = reg.counter(
+                    "repro_kv_live_bytes_total",
+                    "KV bytes of the live positions those steps needed")
             self._h_tick = reg.histogram(
                 "repro_sched_tick_seconds",
                 "wall time of one scheduler tick")
@@ -201,43 +219,27 @@ class ChunkedPrefillScheduler:
         return False
 
     # ------------------------------------------------------------ tick
-    def tick(self):
-        if self.obs is None:
-            self._run_tick()
-            return
-        eng, tr = self.eng, self.obs.tracer
-        t0 = eng.clock()
-        sp = tr.begin("scheduler", "tick", cat="sched",
-                      queued=len(eng.queue), running=len(eng.running))
-        self._run_tick()
-        tr.end(sp)
-        self._h_tick.observe(eng.clock() - t0)
-        self._g_queue.set(len(eng.queue))
-        self._g_running.set(len(eng.running))
+    def _phase(self, name: str, **kw) -> Phase:
+        """A host phase of the scheduler track (``repro.scheduler.*``)."""
+        return Phase(self._tracer, "scheduler", name, "sched", **kw)
 
-    def _run_tick(self):
-        if self.obs is not None:
-            # direct begin/end (no contextmanager frame) and no child
-            # span when the phase has no work — decode-heavy ticks with
-            # an empty queue stay one event, not three
-            tr = self.obs.tracer
-            if self.eng.queue or self.eng.handoffs:
-                sp = tr.begin("scheduler", "admit", cat="sched")
-                self._admit_tick()
-                tr.end(sp)
-            else:
-                self._admit_tick()
-            if self.eng.running:
-                sp = tr.begin("scheduler", "decode", cat="sched")
-                self._decode_tick()
-                tr.end(sp)
-            else:
-                self._decode_tick()
+    def tick(self):
+        eng, obs = self.eng, self.obs
+        t0 = eng.clock() if obs is not None else 0.0
+        with self._phase("tick", step=eng.steps, queued=len(eng.queue),
+                         running=len(eng.running)):
+            # no child span when the phase has no work
+            if eng.queue or eng.handoffs:
+                with self._phase("admit"):
+                    self._admit_tick()
+            if eng.running:
+                with self._phase("decode"):
+                    self._decode_tick()
             self._degrade_update()
-            return
-        self._admit_tick()
-        self._decode_tick()
-        self._degrade_update()
+        if obs is not None:
+            self._h_tick.observe(eng.clock() - t0)
+            self._g_queue.set(len(eng.queue))
+            self._g_running.set(len(eng.running))
 
     def _admit_tick(self):
         if self.degrade_level >= 2:
@@ -427,13 +429,16 @@ class ChunkedPrefillScheduler:
         if req.extras:
             batch.update({k: jnp.asarray(v) for k, v in req.extras.items()})
         lo, ai = self._lora_args([aid])
-        logits, cache, _ = eng._prefill(eng.params, batch, lo, ai)
+        rid = req.request_id
+        with self._phase("prefill", rid=rid):
+            logits, cache, _ = eng._prefill(eng.params, batch, lo, ai)
         from repro.models import model as M
-        if self.paged:
-            eng.slots.insert_prefill(slot, cache, chunk + n_front)
-        else:
-            cache = M.pad_cache(eng.cfg, cache, eng.capacity)
-            eng.slots.insert(slot, cache, chunk + n_front)
+        with self._phase("kv_insert", rid=rid):
+            if self.paged:
+                eng.slots.insert_prefill(slot, cache, chunk + n_front)
+            else:
+                cache = M.pad_cache(eng.cfg, cache, eng.capacity)
+                eng.slots.insert(slot, cache, chunk + n_front)
 
         if chunk < n:
             self.pending[slot] = chunk
@@ -442,8 +447,9 @@ class ChunkedPrefillScheduler:
             self._handoff_out(slot, req)
         else:
             self._store_prompt(slot, req)
-            tok = eng._sample(logits, req)
-            self._emit(slot, req, int(tok[0]))
+            with self._phase("first_token", rid=rid):
+                tok = int(eng._sample(logits, req)[0])
+            self._emit(slot, req, tok)
         return True
 
     # ----------------------------------------------------------- handoff
@@ -742,78 +748,99 @@ class ChunkedPrefillScheduler:
         if (eng.drafter is not None and self.degrade_level < 1
                 and any(s not in self.pending for s in eng.running)):
             return self._spec_micro_step()
-        if eng.paged:
-            self._grow_all()
-        if not eng.running:
-            return
-        if self.obs is None:
-            return self._micro_step_body()
-        # hot path: direct begin/end, O(1) slot counts (pending is a
-        # subset of running), occupancy is one bisect
-        self._h_occupancy.observe(len(eng.running) / eng.slots.B)
-        tr = self.obs.tracer
-        npre = len(self.pending)
-        sp = tr.begin("scheduler", "micro_step", cat="sched",
-                      decoding=len(eng.running) - npre, prefilling=npre)
-        self._micro_step_body()
-        tr.end(sp)
+        with self._phase("micro_step") as sp:
+            if eng.paged:
+                with self._phase("grow"):
+                    self._grow_all()
+            if not eng.running:
+                return
+            npre = len(self.pending)
+            sp.set(decoding=len(eng.running) - npre, prefilling=npre)
+            with self._phase("inputs"):
+                B = eng.slots.B
+                toks = np.zeros((B, 1), np.int32)
+                advance = np.zeros((B,), bool)
+                for slot, req in eng.running.items():
+                    advance[slot] = True
+                    toks[slot, 0] = (req.prompt[self.pending[slot]]
+                                     if slot in self.pending
+                                     else req.generated[-1])
+                samp = self._sampling_inputs()
+                if eng.paged:
+                    lengths = np.where(advance, eng.slots.lengths + 1,
+                                       eng.slots.lengths).astype(np.int32)
+                    kv = (eng.slots.pool, eng.slots.tables_device(),
+                          jnp.asarray(lengths))
+                else:
+                    lengths = jnp.where(jnp.asarray(advance),
+                                        eng.slots.lengths + 1,
+                                        eng.slots.lengths)
+                    kv = (eng.slots.cache, lengths)
+                toks = jnp.asarray(toks)
+            if eng.paged:
+                sp.set(**self._kv_counts(lengths))
+            with self._phase("dispatch"):
+                if eng.paged:
+                    out, eng.slots.pool = eng._decode_sample_paged(
+                        eng.params, toks, *kv, *samp)
+                else:
+                    out, eng.slots.cache = eng._decode_sample(
+                        eng.params, toks, *kv, *samp)
+            eng.slots.lengths = lengths
+            with self._phase("device_wait"):
+                sampled = np.asarray(out)   # one device_get for the batch
+            with self._phase("emit"):
+                for slot, req in list(eng.running.items()):
+                    if slot in self.pending:
+                        self.pending[slot] += 1
+                        if self.pending[slot] >= len(req.prompt):
+                            # last prompt token consumed: its logits are
+                            # the next-token logits — prefill is complete
+                            del self.pending[slot]
+                            self._store_prompt(slot, req)
+                            if self.role == "prefill":
+                                self._handoff_out(slot, req)
+                            else:
+                                self._emit(slot, req, int(sampled[slot]))
+                    else:
+                        self._emit(slot, req, int(sampled[slot]))
 
-    def _micro_step_body(self):
+    def _sampling_inputs(self):
+        """The step's sampling arguments as the fused jits take them:
+        ``(key, temps, top_ks, top_ps, lora, adapter_ids, greedy)``, with
+        a fresh key split off the engine's, and the batch occupancy
+        sampled into the registry."""
         eng = self.eng
         B = eng.slots.B
-        toks = np.zeros((B, 1), np.int32)
-        advance = np.zeros((B,), bool)
+        if self.obs is not None:
+            self._h_occupancy.observe(len(eng.running) / B)
         temps = np.zeros((B,), np.float32)
         tks = np.zeros((B,), np.int32)
         tps = np.ones((B,), np.float32)
         for slot, req in eng.running.items():
-            advance[slot] = True
-            if slot in self.pending:
-                toks[slot, 0] = req.prompt[self.pending[slot]]
-            else:
-                toks[slot, 0] = req.generated[-1]
             temps[slot] = req.temperature
             tks[slot] = req.top_k
             tps[slot] = req.top_p
-        greedy = bool(np.all(temps <= 0.0))
         aids = np.zeros((B,), np.int32)
         for slot, idx in self._slot_adapter.items():
             aids[slot] = idx
         lo, ai = self._lora_args(aids)
         eng.key, key = jax.random.split(eng.key)
-        if eng.paged:
-            lengths = np.where(advance, eng.slots.lengths + 1,
-                               eng.slots.lengths).astype(np.int32)
-            out, new_pool = eng._decode_sample_paged(
-                eng.params, jnp.asarray(toks), eng.slots.pool,
-                eng.slots.tables_device(), jnp.asarray(lengths), key,
-                jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps),
-                lo, ai, greedy)
-            eng.slots.pool = new_pool
-        else:
-            lengths = jnp.where(jnp.asarray(advance),
-                                eng.slots.lengths + 1, eng.slots.lengths)
-            out, new_cache = eng._decode_sample(
-                eng.params, jnp.asarray(toks), eng.slots.cache, lengths,
-                key, jnp.asarray(temps), jnp.asarray(tks),
-                jnp.asarray(tps), lo, ai, greedy)
-            eng.slots.cache = new_cache
-        eng.slots.lengths = lengths
-        sampled = np.asarray(out)          # one device_get for the batch
-        for slot, req in list(eng.running.items()):
-            if slot in self.pending:
-                self.pending[slot] += 1
-                if self.pending[slot] >= len(req.prompt):
-                    # last prompt token consumed: its logits are the
-                    # next-token logits — prefill is complete
-                    del self.pending[slot]
-                    self._store_prompt(slot, req)
-                    if self.role == "prefill":
-                        self._handoff_out(slot, req)
-                    else:
-                        self._emit(slot, req, int(sampled[slot]))
-            else:
-                self._emit(slot, req, int(sampled[slot]))
+        return (key, jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps),
+                lo, ai, bool(np.all(temps <= 0.0)))
+
+    def _kv_counts(self, lengths) -> Dict[str, int]:
+        """KV positions a paged step needs (its running slots' lengths,
+        the new tokens included) and the positions its attention reads;
+        with ``obs`` attached both go into the registry as bytes."""
+        eng = self.eng
+        live = int(sum(int(lengths[s]) for s in eng.running))
+        read = paged_kv_read_tokens(eng.slots.B, eng.slots.blocks_per_seq,
+                                    eng.slots.block_size)
+        if self.obs is not None:
+            self._c_kv_live.inc(live * self._kv_token_bytes)
+            self._c_kv_read.inc(read * self._kv_token_bytes)
+        return {"live_tokens": live, "kv_read_tokens": read}
 
     def _spec_micro_step(self):
         """One speculative verify micro-step (variable tokens per tick).
@@ -835,83 +862,76 @@ class ChunkedPrefillScheduler:
         pool (``PagedCacheSlots.trim``).
         """
         eng = self.eng
-        T = eng.spec_k + 1
-        if eng.paged:
-            self._grow_all(T)
-        if not eng.running:
-            return
-        if self.obs is None:
-            return self._spec_body(T)
-        self._h_occupancy.observe(len(eng.running) / eng.slots.B)
-        tr = self.obs.tracer
-        sp = tr.begin("scheduler", "spec_verify", cat="sched",
-                      slots=len(eng.running), k=eng.spec_k)
-        self._spec_body(T)
-        tr.end(sp)
+        k = eng.spec_k
+        T = k + 1
+        with self._phase("spec_verify", k=k) as sp:
+            if eng.paged:
+                with self._phase("grow"):
+                    self._grow_all(T)
+            if not eng.running:
+                return
+            sp.set(slots=len(eng.running))
+            B = eng.slots.B
+            with self._phase("draft"):
+                toks = np.zeros((B, T), np.int32)
+                nd = np.zeros((B,), np.int32)
+                # deterministic drafters (q = one-hot) skip the dense
+                # (B,k,V) host buffer: the accept jit rebuilds q from
+                # the token ids
+                det = eng.drafter.deterministic
+                dprobs = (None if det else
+                          np.zeros((B, k, eng.cfg.vocab_padded), np.float32))
+                advance = np.zeros((B,), bool)
+                for slot, req in eng.running.items():
+                    advance[slot] = True
+                    if slot in self.pending:
+                        # prefill rows advance exactly one prompt token
+                        toks[slot, 0] = req.prompt[self.pending[slot]]
+                        continue
+                    toks[slot, 0] = req.generated[-1]
+                    # drafting past the remaining budget is wasted
+                    # verification: the launch emits at most n_draft + 1
+                    cap = min(k, req.max_new_tokens - len(req.generated) - 1)
+                    if cap <= 0:
+                        continue
+                    ctx = list(req.prompt) + list(req.generated)
+                    drafts, qp = eng.drafter.propose(slot, ctx, cap,
+                                                     req.temperature)
+                    n = len(drafts)
+                    if n:
+                        toks[slot, 1:1 + n] = drafts
+                        if not det:
+                            dprobs[slot, :n] = qp
+                        nd[slot] = n
+            with self._phase("inputs"):
+                key, temps, tks, tps, lo, ai, greedy = \
+                    self._sampling_inputs()
+                base = np.asarray(eng.slots.lengths, np.int32)
+                lengths = np.where(advance, base + T, base).astype(np.int32)
+                dp = None if det else jnp.asarray(dprobs)
+                args = (jnp.asarray(toks),)
+                args += ((eng.slots.pool, eng.slots.tables_device())
+                         if eng.paged else (eng.slots.cache,))
+                args += (jnp.asarray(lengths), key, temps, tks, tps, dp,
+                         jnp.asarray(nd), lo, ai, greedy)
+            if eng.paged:
+                sp.set(**self._kv_counts(lengths))
+            with self._phase("dispatch"):
+                if eng.paged:
+                    out, nem, eng.slots.pool = eng._verify_paged(
+                        eng.params, *args)
+                else:
+                    out, nem, eng.slots.cache = eng._verify(
+                        eng.params, *args)
+            with self._phase("device_wait"):
+                out = np.asarray(out)       # one device_get for the batch
+                nem = np.asarray(nem)
+            with self._phase("emit"):
+                self._spec_emit(base, out, nem, nd)
 
-    def _spec_body(self, T: int):
+    def _spec_emit(self, base, out, nem, nd):
+        """Roll each slot back to its accepted burst and emit it."""
         eng = self.eng
-        k = T - 1
-        B = eng.slots.B
-        Vp = eng.cfg.vocab_padded
-        toks = np.zeros((B, T), np.int32)
-        nd = np.zeros((B,), np.int32)
-        # deterministic drafters (q = one-hot) skip the dense (B,k,V)
-        # host buffer: the accept jit rebuilds q from the token ids
-        det = eng.drafter.deterministic
-        dprobs = None if det else np.zeros((B, k, Vp), np.float32)
-        advance = np.zeros((B,), bool)
-        temps = np.zeros((B,), np.float32)
-        tks = np.zeros((B,), np.int32)
-        tps = np.ones((B,), np.float32)
-        for slot, req in eng.running.items():
-            advance[slot] = True
-            temps[slot] = req.temperature
-            tks[slot] = req.top_k
-            tps[slot] = req.top_p
-            if slot in self.pending:
-                toks[slot, 0] = req.prompt[self.pending[slot]]
-                continue  # prefill rows advance exactly one prompt token
-            toks[slot, 0] = req.generated[-1]
-            # drafting past the remaining budget is wasted verification:
-            # the launch emits at most n_draft + 1 tokens
-            cap = min(k, req.max_new_tokens - len(req.generated) - 1)
-            if cap <= 0:
-                continue
-            ctx = list(req.prompt) + list(req.generated)
-            drafts, qp = eng.drafter.propose(slot, ctx, cap,
-                                             req.temperature)
-            n = len(drafts)
-            if n:
-                toks[slot, 1:1 + n] = drafts
-                if not det:
-                    dprobs[slot, :n] = qp
-                nd[slot] = n
-        greedy = bool(np.all(temps <= 0.0))
-        aids = np.zeros((B,), np.int32)
-        for slot, idx in self._slot_adapter.items():
-            aids[slot] = idx
-        lo, ai = self._lora_args(aids)
-        eng.key, key = jax.random.split(eng.key)
-        base = np.asarray(eng.slots.lengths, np.int32)
-        lengths = np.where(advance, base + T, base).astype(np.int32)
-        dp = None if det else jnp.asarray(dprobs)
-        if eng.paged:
-            out, nem, new_pool = eng._verify_paged(
-                eng.params, jnp.asarray(toks), eng.slots.pool,
-                eng.slots.tables_device(), jnp.asarray(lengths), key,
-                jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps),
-                dp, jnp.asarray(nd), lo, ai, greedy)
-            eng.slots.pool = new_pool
-        else:
-            out, nem, new_cache = eng._verify(
-                eng.params, jnp.asarray(toks), eng.slots.cache,
-                jnp.asarray(lengths), key, jnp.asarray(temps),
-                jnp.asarray(tks), jnp.asarray(tps), dp,
-                jnp.asarray(nd), lo, ai, greedy)
-            eng.slots.cache = new_cache
-        out = np.asarray(out)           # one device_get for the batch
-        nem = np.asarray(nem)
         # roll lengths back to the accepted burst BEFORE emitting (a
         # finishing _emit releases the slot and resets its length)
         final = base.copy()

@@ -3,6 +3,7 @@ end-to-end instrumentation through the engine, gateway, and trainer."""
 import itertools
 import json
 import math
+import time
 
 import pytest
 
@@ -155,7 +156,6 @@ def test_perfetto_round_trip():
     tr = Tracer(clock=_vclock(), process="test-proc")
     with tr.span("scheduler", "tick"):
         pass
-    tr.counter("scheduler", "queue", depth=3)
     doc = json.loads(tr.to_json())
     evs = doc["traceEvents"]
     assert doc["displayTimeUnit"] == "ms"
@@ -167,7 +167,6 @@ def test_perfetto_round_trip():
     assert "scheduler" in threads
     x = next(e for e in evs if e["ph"] == "X")
     assert x["tid"] == threads["scheduler"] and x["dur"] >= 0
-    assert any(e["ph"] == "C" for e in evs)
 
 
 # ----------------------------------------------- collector edge cases
@@ -279,16 +278,93 @@ def test_engine_instrumented_end_to_end(tiny_cfg, tiny_params):
             and "decode" in names
 
 
-def test_engine_without_obs_unchanged(tiny_cfg, tiny_params):
+def _served(cfg, params, obs=None, prompts=([1, 2], [3, 4, 5, 6, 7])):
     from repro.serving.engine import InferenceEngine, Request
+    eng = InferenceEngine(cfg, params, max_batch=2, capacity=64,
+                          clock=obs.clock if obs else time.monotonic,
+                          obs=obs)
+    reqs = [Request(prompt=list(p), max_new_tokens=5) for p in prompts]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_until_idle()
+    return eng, [list(r.generated) for r in reqs]
+
+
+def test_engine_without_obs_unchanged(tiny_cfg, tiny_params):
     from repro.serving.metrics import MetricsCollector
-    eng = InferenceEngine(tiny_cfg, tiny_params, max_batch=2, capacity=64)
-    assert eng.obs is None
+    eng, toks = _served(tiny_cfg, tiny_params)
+    assert eng.obs is None and eng.scheduler._tracer is None
     assert type(eng.metrics) is MetricsCollector
-    eng.submit(Request(prompt=[1, 2], max_new_tokens=2))
-    assert eng.run_until_idle()["completed"] == 1
+    assert eng.metrics.summary()["completed"] == 2
     with pytest.raises(ValueError):
         eng.collect_metrics()            # no registry anywhere
+    # the phases still open (as profiler annotations) and change nothing
+    # the engine serves: the same tokens as with obs attached
+    obs = Observability(clock=_vclock())
+    _, toks_obs = _served(tiny_cfg, tiny_params, obs)
+    assert toks == toks_obs and all(len(t) == 5 for t in toks)
+    assert obs.tracer.n_events > 0
+
+
+# the scheduler track's spans before the profiler phases, and the phases
+SCHED_SPANS = {"tick", "admit", "decode", "micro_step"}
+PHASES = {"grow", "inputs", "dispatch", "device_wait", "emit", "prefill",
+          "kv_insert", "first_token"}
+
+
+def test_scheduler_track_keeps_its_spans_and_adds_the_phases(
+        tiny_cfg, tiny_params):
+    obs = Observability(clock=_vclock())
+    eng, _ = _served(tiny_cfg, tiny_params, obs)
+    assert eng.paged
+    evs = [e for e in obs.tracer.events_for("scheduler") if e["ph"] == "X"]
+    names = {e["name"] for e in evs}
+    assert names == SCHED_SPANS | PHASES
+    ticks = [e for e in evs if e["name"] == "tick"]
+    assert [set(e["args"]) for e in ticks[:1]] == [{"queued", "running"}]
+    rids = set(eng.metrics.requests)
+    for e in evs:
+        if e["name"] in ("prefill", "kv_insert", "first_token"):
+            assert e["args"]["rid"] in rids
+    # every phase of a micro-step nests inside it
+    steps = [e for e in evs if e["name"] == "micro_step"]
+    for e in evs:
+        if e["name"] in ("inputs", "dispatch", "device_wait", "emit"):
+            assert any(m["ts"] <= e["ts"] and e["ts"] + e["dur"]
+                       <= m["ts"] + m["dur"] for m in steps), e
+
+
+def test_micro_step_counts_live_and_read_kv(tiny_cfg, tiny_params):
+    from repro.models.attention import paged_kv_read_tokens
+    from repro.serving.engine import InferenceEngine, Request
+    obs = Observability(clock=_vclock())
+    eng = InferenceEngine(tiny_cfg, tiny_params, max_batch=2, capacity=64,
+                          clock=obs.clock, obs=obs)
+    for p in ([1, 2, 3], [4, 5, 6, 7, 8]):
+        eng.submit(Request(prompt=p, max_new_tokens=6))
+    live_seen = []
+    while eng.num_active:
+        before = len([e for e in obs.tracer.events_for("scheduler")
+                      if e["name"] == "micro_step"])
+        running = set(eng.running)
+        eng.step()
+        steps = [e for e in obs.tracer.events_for("scheduler")
+                 if e["name"] == "micro_step"][before:]
+        for e in steps:
+            live_seen.append(e["args"]["live_tokens"])
+            assert e["args"]["kv_read_tokens"] == paged_kv_read_tokens(
+                2, 64 // 16, 16) == 2 * eng.slots.tables.shape[1] * 16
+        if steps and running == set(eng.running):
+            # nothing finished: the last step's live tokens are the
+            # running slots' lengths now
+            assert live_seen[-1] == sum(int(eng.slots.lengths[s])
+                                        for s in eng.running)
+    assert live_seen and all(0 < n <= 2 * 64 for n in live_seen)
+    snap = obs.registry.snapshot()
+    per = eng.kv_stats()["kv_block_bytes_per_device"] // 16
+    assert snap["repro_kv_live_bytes_total"] == sum(live_seen) * per
+    assert snap["repro_kv_read_bytes_total"] == (
+        len(live_seen) * 2 * 4 * 16 * per)
 
 
 def test_gateway_rejections_counted():
