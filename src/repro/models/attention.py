@@ -244,117 +244,95 @@ def verify_attention(q, k_cache, v_cache, lengths):
     return jnp.moveaxis(o, 3, 1).reshape(B, S, H, hd)
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths):
-    """One-token attention against a *paged* cache (jnp oracle).
+def pool_index(layer, *idx):
+    """Index tuple into a paged pool leaf: a stacked ``(layers,
+    num_blocks, ...)`` leaf takes the layer first, a lone layer's
+    ``(num_blocks, ...)`` leaf (``layer`` None) does not."""
+    return idx if layer is None else (layer,) + idx
 
-    q: (B,1,H,hd); k_pool/v_pool: (num_blocks, block_size, KV, hd) shared
-    physical pool; block_tables: (B, max_blocks) int32 physical block ids;
-    lengths: (B,) valid tokens (the new token's KV must already be
-    written).  Gathers each sequence's blocks into logical order and runs
-    the dense decode math — the Pallas kernel
-    (``repro.kernels.paged_attention``) implements the same contract on
-    TPU by walking the table in SMEM instead of materializing the gather.
-    """
-    B = q.shape[0]
-    _, blk, KV, hd = k_pool.shape
-    W = block_tables.shape[1]
+
+def pool_axes(layer, *axes):
+    """Logical axes of a paged pool leaf, ``"layers"`` first when the
+    leaf is stacked (see :func:`pool_index`)."""
+    return axes if layer is None else ("layers",) + axes
+
+
+def _gather_heads(pool, scale, block_tables, layer, hd):
+    """Each row's blocks of a flat GQA pool leaf ``(…, num_blocks,
+    block_size, KV·hd)`` in logical order, ``(B, W·block_size, KV, hd)``.
+    An int8 leaf comes with its ``(…, num_blocks, KV)`` scale and is
+    dequantized to f32; otherwise ``scale`` is None."""
+    rows = pool[pool_index(layer, block_tables)]        # (B, W, blk, KV·hd)
+    B, W, blk, F = rows.shape
+    rows = rows.reshape(B, W, blk, F // hd, hd)
+    if scale is not None:
+        rows = (rows.astype(jnp.float32)
+                * scale[pool_index(layer, block_tables)][:, :, None, :, None])
     # the gather moves block/position dims only; pin the head axis so a
     # TP partitioner keeps the gathered sequence head-sharded like the
     # pool (no-op without active sharding rules)
-    k_seq = sharding.constrain(
-        k_pool[block_tables].reshape(B, W * blk, KV, hd),
-        ("act_batch", "act_kvseq", "act_heads", None))
-    v_seq = sharding.constrain(
-        v_pool[block_tables].reshape(B, W * blk, KV, hd),
-        ("act_batch", "act_kvseq", "act_heads", None))
-    return decode_attention(q, k_seq, v_seq, lengths)
+    return sharding.constrain(rows.reshape(B, W * blk, F // hd, hd),
+                              ("act_batch", "act_kvseq", "act_heads", None))
+
+
+def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
+                           layer=None, k_scale=None, v_scale=None):
+    """One-token attention against a *paged* cache (jnp oracle).
+
+    q: (B,1,H,hd); k_pool/v_pool: the shared physical pool's flat leaves,
+    ``(num_blocks, block_size, KV·hd)``, or stacked ``(layers, ...)`` and
+    read at ``layer``; block_tables: (B, max_blocks) int32 physical block
+    ids; lengths: (B,) valid tokens (the new token's KV must already be
+    written).  An int8 pool passes its ``(…, num_blocks, KV)`` f32
+    scales and is dequantized as it is gathered.  Gathers each
+    sequence's blocks into logical order and runs the dense decode math
+    — the Pallas kernel (``repro.kernels.paged_attention``) implements
+    the same contract on TPU by walking the table in SMEM instead of
+    materializing the gather.
+    """
+    hd = q.shape[-1]
+    return decode_attention(
+        q, _gather_heads(k_pool, k_scale, block_tables, layer, hd),
+        _gather_heads(v_pool, v_scale, block_tables, layer, hd), lengths)
 
 
 def paged_kv_read_tokens(batch: int, table_width: int,
                          block_size: int) -> int:
     """KV positions one layer's paged attention reads per step:
-    :func:`paged_decode_attention` (and the verify, int8 and latent
-    variants) gathers every row's whole block table, ``batch x
-    table_width x block_size`` positions, whatever the live lengths are.
-    Keep it beside the gather so that it changes when the gather does."""
+    :func:`paged_decode_attention` (and the verify and latent variants)
+    gathers every row's whole block table, ``batch x table_width x
+    block_size`` positions, whatever the live lengths are.  Keep it
+    beside the gather so that it changes when the gather does."""
     return batch * table_width * block_size
 
 
-def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths):
+def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths,
+                           layer=None, k_scale=None, v_scale=None):
     """Multi-token tail attention against a *paged* cache (jnp oracle).
 
     q: (B,S,H,hd) — the S newest tokens, KV already scattered into the
-    pool; lengths: (B,) valid tokens including all S.  Gathers each
-    sequence's blocks into logical order and runs :func:`verify_attention`
-    — the Pallas kernel (``repro.kernels.paged_attention.paged_verify``)
-    implements the same contract on TPU by walking the table in SMEM.
+    pool; lengths: (B,) valid tokens including all S; the pool as in
+    :func:`paged_decode_attention`.  Gathers each sequence's blocks into
+    logical order and runs :func:`verify_attention` — the Pallas kernel
+    (``repro.kernels.paged_attention.paged_verify``) implements the same
+    contract on TPU by walking the table in SMEM.
     """
-    B = q.shape[0]
-    _, blk, KV, hd = k_pool.shape
-    W = block_tables.shape[1]
-    k_seq = sharding.constrain(
-        k_pool[block_tables].reshape(B, W * blk, KV, hd),
-        ("act_batch", "act_kvseq", "act_heads", None))
-    v_seq = sharding.constrain(
-        v_pool[block_tables].reshape(B, W * blk, KV, hd),
-        ("act_batch", "act_kvseq", "act_heads", None))
-    return verify_attention(q, k_seq, v_seq, lengths)
+    hd = q.shape[-1]
+    return verify_attention(
+        q, _gather_heads(k_pool, k_scale, block_tables, layer, hd),
+        _gather_heads(v_pool, v_scale, block_tables, layer, hd), lengths)
 
 
-def paged_decode_attention_int8(q, k_pool, v_pool, k_scale, v_scale,
-                                block_tables, lengths):
-    """One-token attention against an *int8* paged cache (jnp oracle).
-
-    k_pool/v_pool: (num_blocks, block_size, KV, hd) int8; k_scale/v_scale:
-    (num_blocks, KV) f32 symmetric per-block-per-head scales.  Gathers and
-    dequantizes each sequence's blocks, then runs the dense decode math —
-    the fused Pallas kernel keeps the HBM read int8 and dequantizes
-    in-register instead.
-    """
-    B = q.shape[0]
-    _, blk, KV, hd = k_pool.shape
-    W = block_tables.shape[1]
-    k_seq = sharding.constrain(
-        (k_pool[block_tables].astype(jnp.float32)
-         * k_scale[block_tables][:, :, None, :, None]
-         ).reshape(B, W * blk, KV, hd),
-        ("act_batch", "act_kvseq", "act_heads", None))
-    v_seq = sharding.constrain(
-        (v_pool[block_tables].astype(jnp.float32)
-         * v_scale[block_tables][:, :, None, :, None]
-         ).reshape(B, W * blk, KV, hd),
-        ("act_batch", "act_kvseq", "act_heads", None))
-    return decode_attention(q, k_seq, v_seq, lengths)
-
-
-def paged_verify_attention_int8(q, k_pool, v_pool, k_scale, v_scale,
-                                block_tables, lengths):
-    """Multi-token tail attention against an *int8* paged cache (jnp
-    oracle); same contract as :func:`paged_verify_attention` with
-    gather-time dequantization."""
-    B = q.shape[0]
-    _, blk, KV, hd = k_pool.shape
-    W = block_tables.shape[1]
-    k_seq = sharding.constrain(
-        (k_pool[block_tables].astype(jnp.float32)
-         * k_scale[block_tables][:, :, None, :, None]
-         ).reshape(B, W * blk, KV, hd),
-        ("act_batch", "act_kvseq", "act_heads", None))
-    v_seq = sharding.constrain(
-        (v_pool[block_tables].astype(jnp.float32)
-         * v_scale[block_tables][:, :, None, :, None]
-         ).reshape(B, W * blk, KV, hd),
-        ("act_batch", "act_kvseq", "act_heads", None))
-    return verify_attention(q, k_seq, v_seq, lengths)
-
-
-def quantized_scatter_token(pool, scales, x_t, pb, off):
+def quantized_scatter_token(pool, scales, x_t, pb, off, layer=None):
     """Scatter one token's values into an int8 paged pool leaf.
 
-    pool: (num_blocks, blk, *inner) int8; scales: (num_blocks,) or
-    (num_blocks, heads) f32 — one symmetric scale per block (per head when
+    pool: (…, num_blocks, blk, F) int8, stacked leaves read and written at
+    ``layer`` (see :func:`pool_index`); scales: (…, num_blocks) or (…,
+    num_blocks, heads) f32 — one symmetric scale per block (per head when
     the leaf has a head axis, reducing over everything else); x_t:
-    (B, *inner) new values; pb/off: (B,) physical block and in-block slot.
+    (B, *inner) new values, whose ``inner`` holds F elements (a GQA
+    leaf stores a position's heads flat); pb/off: (B,) physical block and
+    in-block slot.
 
     The block scale is a running max: if the new token raises it, the
     block's resident rows are requantized under the wider scale (gather
@@ -365,44 +343,49 @@ def quantized_scatter_token(pool, scales, x_t, pb, off):
     Duplicate ``pb`` rows only occur for inert slots parked on the
     reserved null block 0, which is never read.
     """
-    blk = pool.shape[1]
-    per_head = scales.ndim == 2
+    at = pool_index(layer, pb)
+    blk = pool.shape[-2]
     x = x_t.astype(jnp.float32)
-    q_old = pool[pb].astype(jnp.float32)            # (B, blk, *inner)
-    s_old = scales[pb]                              # (B,) or (B, heads)
-    if per_head:
-        s_tok = jnp.max(jnp.abs(x), axis=-1) / 127.0      # (B, heads)
+    B = x.shape[0]
+    q_old = pool[at].astype(jnp.float32).reshape(
+        (B, blk) + x.shape[1:])                      # (B, blk, *inner)
+    s_old = scales[at]                              # (B,) or (B, heads)
+    if s_old.ndim == 2:
         bcast = (slice(None), None, slice(None), None)    # -> (B,1,h,1)
         tokb = (slice(None), slice(None), None)           # -> (B,h,1)
     else:
-        s_tok = jnp.max(jnp.abs(x), axis=-1) / 127.0      # (B,)
         bcast = (slice(None), None, None)                 # -> (B,1,1)
         tokb = (slice(None), None)                        # -> (B,1)
+    s_tok = jnp.max(jnp.abs(x), axis=-1) / 127.0          # (B,) or (B,h)
     s_new = jnp.maximum(s_old, s_tok)
     denom = jnp.maximum(s_new, 1e-12)
     ratio = jnp.where(s_new > 0, s_old / denom, 0.0)
     q_res = jnp.round(q_old * ratio[bcast])
     q_tok = jnp.clip(jnp.round(x / denom[tokb]), -127, 127)
     sel = jnp.arange(blk) == off[:, None]                 # (B, blk)
-    sel = sel.reshape(sel.shape + (1,) * (pool.ndim - 2))
+    sel = sel.reshape(sel.shape + (1,) * (x.ndim - 1))
     blk_new = jnp.where(sel, q_tok[:, None], q_res)
-    pool = pool.at[pb].set(blk_new.astype(pool.dtype))
-    scales = scales.at[pb].set(s_new)
+    pool = pool.at[at].set(
+        blk_new.reshape(B, blk, -1).astype(pool.dtype))
+    scales = scales.at[at].set(s_new)
     return pool, scales
 
 
 def attention_block(cfg: ModelConfig, p, x, positions, *,
                     mode: str, cache=None, lengths=None,
                     kv_valid_len=None, causal: bool = True,
-                    block_tables=None, lora=None, adapter_ids=None):
+                    block_tables=None, layer=None, lora=None,
+                    adapter_ids=None):
     """Full attention sublayer.  Returns (out (B,S,d), new_cache or None).
 
     mode: "train" | "prefill" | "decode".
     cache (decode): dict(k=(B,Smax,KV,hd), v=...); ``lengths`` (B,) counts
     valid entries *including* the token being decoded.  With
-    ``block_tables`` (B, max_blocks), cache leaves are instead pool-shaped
-    (num_blocks, block_size, KV, hd) and the new token's KV is scattered
-    into its sequence's current block.  ``lora`` + ``adapter_ids`` apply
+    ``block_tables`` (B, max_blocks), cache leaves are instead the paged
+    pool's flat (num_blocks, block_size, KV·hd) leaves — stacked
+    (layers, ...) and read and written at ``layer`` when the layer scan
+    carries the whole pool — and the new token's KV is scattered into its
+    sequence's current block in place.  ``lora`` + ``adapter_ids`` apply
     per-row multi-LoRA shifts to the targeted projections (see
     :func:`lora_shift`); adapter id 0 is the base model.
     """
@@ -423,80 +406,55 @@ def attention_block(cfg: ModelConfig, p, x, positions, *,
         if mode == "prefill":
             with jax.named_scope("kv_write"):
                 new_cache = {"k": k.astype(dt), "v": v.astype(dt)}
-    elif block_tables is not None and "k_scale" in cache:
-        # int8 pool: symmetric per-block-per-head scales, quantized at
-        # write time (running-max block scale, see
-        # :func:`quantized_scatter_token`); attention reads dequantize on
-        # gather (the fused Pallas kernel dequantizes in-register)
-        q, k, v = project_qkv(cfg, p, x, positions,
-                              lora=lora, adapter_ids=adapter_ids)
-        S = q.shape[1]
-        blk = cache["k"].shape[1]
-        k_pool, v_pool = cache["k"], cache["v"]
-        k_sc, v_sc = cache["k_scale"], cache["v_scale"]
-        with jax.named_scope("kv_write"):
-            for t in range(S):
-                idx = lengths - S + t
-                pb = jnp.take_along_axis(block_tables,
-                                         (idx // blk)[:, None], axis=1)[:, 0]
-                off = idx % blk
-                k_pool, k_sc = quantized_scatter_token(k_pool, k_sc,
-                                                       k[:, t], pb, off)
-                v_pool, v_sc = quantized_scatter_token(v_pool, v_sc,
-                                                       v[:, t], pb, off)
-            k_pool = sharding.constrain(
-                k_pool, ("act_batch", "act_kvseq", "act_heads", None))
-            v_pool = sharding.constrain(
-                v_pool, ("act_batch", "act_kvseq", "act_heads", None))
-            k_sc = sharding.constrain(k_sc, ("act_batch", "act_heads"))
-            v_sc = sharding.constrain(v_sc, ("act_batch", "act_heads"))
-        with jax.named_scope("attn"):
-            if S == 1:
-                o = paged_decode_attention_int8(q, k_pool, v_pool, k_sc,
-                                                v_sc, block_tables, lengths)
-            else:
-                o = paged_verify_attention_int8(q, k_pool, v_pool, k_sc,
-                                                v_sc, block_tables, lengths)
-        new_cache = {"k": k_pool, "v": v_pool,
-                     "k_scale": k_sc, "v_scale": v_sc}
     elif block_tables is not None:
         q, k, v = project_qkv(cfg, p, x, positions,
                               lora=lora, adapter_ids=adapter_ids)
         S = q.shape[1]
-        blk = cache["k"].shape[1]
-        k_cache, v_cache = cache["k"], cache["v"]
+        blk = cache["k"].shape[-2]
+        quant = "k_scale" in cache
+        new_cache = dict(cache)
         # scatter the S tail tokens' KV (S > 1 = speculative verify; a
         # tail may straddle a block boundary, so resolve each position's
         # physical block separately — S is a static jit constant).  Inert
         # rows have lengths == 1, so their (clamped-negative) positions
-        # resolve to table column 0 == the reserved null block.
+        # resolve to table column 0 == the reserved null block.  An int8
+        # pool quantizes at write time (running-max block scale, see
+        # :func:`quantized_scatter_token`) and dequantizes on gather (the
+        # fused Pallas kernel dequantizes in-register)
         with jax.named_scope("kv_write"):
             for t in range(S):
                 idx = lengths - S + t
                 pb = jnp.take_along_axis(block_tables,
                                          (idx // blk)[:, None], axis=1)[:, 0]
                 off = idx % blk
-                k_cache = k_cache.at[pb, off].set(
-                    k[:, t].astype(k_cache.dtype))
-                v_cache = v_cache.at[pb, off].set(
-                    v[:, t].astype(v_cache.dtype))
-            # pool leaves are (num_blocks, block_size, KV, hd): the block
-            # and in-block dims sit in the (act_batch, act_kvseq) slots,
-            # which a serving rule set maps to None — so this pins
-            # exactly the head axis and the updated pool keeps the input
-            # pool's sharding
-            k_cache = sharding.constrain(
-                k_cache, ("act_batch", "act_kvseq", "act_heads", None))
-            v_cache = sharding.constrain(
-                v_cache, ("act_batch", "act_kvseq", "act_heads", None))
+                for name, x_t in (("k", k[:, t]), ("v", v[:, t])):
+                    if quant:
+                        new_cache[name], new_cache[name + "_scale"] = (
+                            quantized_scatter_token(
+                                new_cache[name], new_cache[name + "_scale"],
+                                x_t, pb, off, layer))
+                    else:
+                        leaf = new_cache[name]
+                        new_cache[name] = leaf.at[
+                            pool_index(layer, pb, off)].set(
+                            x_t.reshape(B, -1).astype(leaf.dtype))
+            # the block and in-block dims sit in the (act_batch,
+            # act_kvseq) slots, which a serving rule set maps to None —
+            # so this pins exactly the head axis and the updated pool
+            # keeps the input pool's sharding
+            for name in new_cache:
+                new_cache[name] = sharding.constrain(
+                    new_cache[name],
+                    pool_axes(layer, "act_batch", "act_heads")
+                    if name.endswith("_scale") else
+                    pool_axes(layer, "act_batch", "act_kvseq", "act_heads"))
+        scales = ((new_cache["k_scale"], new_cache["v_scale"]) if quant
+                  else (None, None))
         with jax.named_scope("attn"):
-            if S == 1:
-                o = paged_decode_attention(q, k_cache, v_cache,
-                                           block_tables, lengths)
-            else:
-                o = paged_verify_attention(q, k_cache, v_cache,
-                                           block_tables, lengths)
-        new_cache = {"k": k_cache, "v": v_cache}
+            attend = (paged_decode_attention if S == 1
+                      else paged_verify_attention)
+            o = attend(q, new_cache["k"], new_cache["v"], block_tables,
+                       lengths, layer, *scales)
     else:
         q, k, v = project_qkv(cfg, p, x, positions,
                               lora=lora, adapter_ids=adapter_ids)

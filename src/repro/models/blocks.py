@@ -79,13 +79,14 @@ def _gather_fsdp(p, specs):
 def apply_layer(cfg: ModelConfig, p, x, positions, *, mixer: str, ffn: str,
                 mode: str, cache=None, lengths=None, causal: bool = True,
                 enc_out=None, cross_cache=None, block_tables=None,
-                lora=None, adapter_ids=None):
+                layer=None, lora=None, adapter_ids=None):
     """Returns (x, new_cache, new_cross_cache, aux).  ``block_tables``
     switches attention mixers to the paged-pool decode path (SSM mixers
-    have no per-position KV and never see it).  ``lora`` is this layer's
-    slice of the stacked multi-LoRA adapter tree (``{"mixer": {target:
-    {"a", "b"}}}``); with per-row ``adapter_ids`` the attention mixers add
-    each row's adapter shift (see ``attention.lora_shift``)."""
+    have no per-position KV and never see it); ``layer`` then indexes a
+    stacked pool, and is None for a lone layer's pool.  ``lora`` is this
+    layer's slice of the stacked multi-LoRA adapter tree (``{"mixer":
+    {target: {"a", "b"}}}``); with per-row ``adapter_ids`` the attention
+    mixers add each row's adapter shift (see ``attention.lora_shift``)."""
     if sharding.active() is not None:
         E_pad = p["moe"]["w_gate"].shape[0] if ffn == "moe" else None
         spec_tree = (dec_layer_specs(cfg) if "cross" in p
@@ -122,11 +123,11 @@ def apply_layer(cfg: ModelConfig, p, x, positions, *, mixer: str, ffn: str,
             o, new_cache = attn_mod.attention_block(
                 cfg, p["mixer"], h, positions, mode=mode, cache=cache,
                 lengths=lengths, causal=causal, block_tables=block_tables,
-                lora=lmix, adapter_ids=adapter_ids)
+                layer=layer, lora=lmix, adapter_ids=adapter_ids)
         elif mixer == "mla":
             o, new_cache = mla_mod.mla_block(
                 cfg, p["mixer"], h, positions, mode=mode, cache=cache,
-                lengths=lengths, block_tables=block_tables,
+                lengths=lengths, block_tables=block_tables, layer=layer,
                 lora=lmix, adapter_ids=adapter_ids)
         elif mixer == "mamba":
             o, new_cache = ssm_mod.mamba_block(

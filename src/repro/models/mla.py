@@ -12,7 +12,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.attention import (NEG_INF, blockwise_attention, lora_shift,
-                                    naive_attention)
+                                    naive_attention, pool_axes, pool_index,
+                                    quantized_scatter_token)
 from repro.models.layers import apply_rope
 from repro.models.param import ParamSpec
 from repro.parallel import sharding
@@ -79,12 +80,14 @@ def _latent_kv(cfg, p, x, positions):
 
 
 def mla_block(cfg: ModelConfig, p, x, positions, *, mode: str,
-              cache=None, lengths=None, block_tables=None, lora=None,
-              adapter_ids=None):
+              cache=None, lengths=None, block_tables=None, layer=None,
+              lora=None, adapter_ids=None):
     """Returns (out, new_cache).  cache: {"ckv": (B,Smax,kvl),
     "kpe": (B,Smax,rope)} — or, with ``block_tables`` (B, max_blocks),
-    pool-shaped {"ckv": (num_blocks, block_size, kvl), ...} with the new
-    latent scattered into the sequence's current block.
+    pool-shaped {"ckv": (num_blocks, block_size, kvl), ...}, stacked
+    (layers, ...) and indexed at ``layer`` when the layer scan carries
+    the whole pool, with the new latent scattered into the sequence's
+    current block in place.
 
     ``lora`` + ``adapter_ids`` add per-row multi-LoRA shifts.  Train/
     prefill applies them to the decompressed projections directly; decode
@@ -135,61 +138,54 @@ def mla_block(cfg: ModelConfig, p, x, positions, *, mode: str,
     # S == 1 is the decode micro-step; S > 1 is the speculative verify
     # tail (the S newest tokens, written then causally attended — each
     # query t sits at position ``lengths - S + t``).
-    if block_tables is not None and "ckv_scale" in cache:
-        # int8 latent pool: the leaves have no head axis, so each block
-        # carries one scalar f32 scale (running-max, requant-on-widen —
-        # same write discipline as the GQA int8 pool)
-        from repro.models.attention import quantized_scatter_token
-
-        blk = cache["ckv"].shape[1]
-        ckv_p, kpe_p = cache["ckv"], cache["kpe"]
-        ckv_s, kpe_s = cache["ckv_scale"], cache["kpe_scale"]
+    if block_tables is not None:
+        # paged latent pool: (…, num_blocks, block_size, kvl|rope) leaves,
+        # stacked ones read and written at ``layer``.  A multi-token tail
+        # may straddle a block boundary: resolve each position's physical
+        # block separately (S is a static constant).  An int8 pool's
+        # leaves have no head axis, so each block carries one scalar f32
+        # scale (running-max, requant-on-widen — same write discipline as
+        # the GQA int8 pool)
+        blk = cache["ckv"].shape[-2]
+        quant = "ckv_scale" in cache
+        new_cache = dict(cache)
         for t in range(S):
             idx = lengths - S + t
             pb = jnp.take_along_axis(block_tables, (idx // blk)[:, None],
                                      axis=1)[:, 0]
             off = idx % blk
-            ckv_p, ckv_s = quantized_scatter_token(ckv_p, ckv_s,
-                                                   c_kv[:, t], pb, off)
-            kpe_p, kpe_s = quantized_scatter_token(kpe_p, kpe_s,
-                                                   k_pe[:, t], pb, off)
-        ckv_p = sharding.constrain(ckv_p, ("act_batch", "act_kvseq", None))
-        kpe_p = sharding.constrain(kpe_p, ("act_batch", "act_kvseq", None))
-        ckv_s = sharding.constrain(ckv_s, ("act_batch",))
-        kpe_s = sharding.constrain(kpe_s, ("act_batch",))
-        new_cache = {"ckv": ckv_p, "kpe": kpe_p,
-                     "ckv_scale": ckv_s, "kpe_scale": kpe_s}
-        # gather + dequantize each sequence's blocks into logical order
-        W = block_tables.shape[1]
-        ckv_c = (ckv_p[block_tables].astype(jnp.float32)
-                 * ckv_s[block_tables][:, :, None, None]
-                 ).reshape(B, W * blk, kvl)
-        kpe_c = (kpe_p[block_tables].astype(jnp.float32)
-                 * kpe_s[block_tables][:, :, None, None]
-                 ).reshape(B, W * blk, rope)
-    elif block_tables is not None:
-        blk = cache["ckv"].shape[1]
-        ckv_p, kpe_p = cache["ckv"], cache["kpe"]
-        # a multi-token tail may straddle a block boundary: resolve each
-        # position's physical block separately (S is a static constant)
-        for t in range(S):
-            idx = lengths - S + t
-            pb = jnp.take_along_axis(block_tables, (idx // blk)[:, None],
-                                     axis=1)[:, 0]
-            off = idx % blk
-            ckv_p = ckv_p.at[pb, off].set(c_kv[:, t].astype(ckv_p.dtype))
-            kpe_p = kpe_p.at[pb, off].set(k_pe[:, t].astype(kpe_p.dtype))
+            for name, x_t in (("ckv", c_kv[:, t]), ("kpe", k_pe[:, t])):
+                if quant:
+                    new_cache[name], new_cache[name + "_scale"] = (
+                        quantized_scatter_token(
+                            new_cache[name], new_cache[name + "_scale"],
+                            x_t, pb, off, layer))
+                else:
+                    leaf = new_cache[name]
+                    new_cache[name] = leaf.at[
+                        pool_index(layer, pb, off)].set(
+                        x_t.astype(leaf.dtype))
         # latent pool leaves have no head axis — under serving_tp these
         # resolve to fully-replicated specs (the MLA cache is small
         # enough to replicate; scores still shard on act_heads below)
-        ckv_p = sharding.constrain(ckv_p, ("act_batch", "act_kvseq", None))
-        kpe_p = sharding.constrain(kpe_p, ("act_batch", "act_kvseq", None))
-        new_cache = {"ckv": ckv_p, "kpe": kpe_p}
-        # gather each sequence's blocks into logical order (jnp oracle;
-        # a paged-MLA Pallas kernel would walk the table in SMEM instead)
-        W = block_tables.shape[1]
-        ckv_c = new_cache["ckv"][block_tables].reshape(B, W * blk, kvl)
-        kpe_c = new_cache["kpe"][block_tables].reshape(B, W * blk, rope)
+        for name in new_cache:
+            new_cache[name] = sharding.constrain(
+                new_cache[name],
+                pool_axes(layer, "act_batch") if name.endswith("_scale")
+                else pool_axes(layer, "act_batch", "act_kvseq", None))
+
+        # gather (and dequantize) each sequence's blocks into logical
+        # order (jnp oracle; a paged-MLA Pallas kernel would walk the
+        # table in SMEM instead)
+        def gather(name):
+            rows = new_cache[name][pool_index(layer, block_tables)]
+            if quant:
+                rows = rows.astype(jnp.float32) * new_cache[
+                    name + "_scale"][pool_index(layer, block_tables)][
+                    :, :, None, None]
+            return rows.reshape(B, -1, rows.shape[-1])
+
+        ckv_c, kpe_c = gather("ckv"), gather("kpe")
     else:
         idx = lengths - S
         ckv_c = jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(

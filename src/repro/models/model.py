@@ -229,88 +229,88 @@ def supports_paged_cache(cfg: ModelConfig) -> bool:
     return True
 
 
+def _layer_pool_struct(cfg: ModelConfig, mixer: str, nb: int, bs: int,
+                       dtype, quantized: bool):
+    """One layer of a paged pool: ``(num_blocks, block_size, ...)`` leaves.
+    A GQA leaf stores a position's heads flat, ``(nb, bs, KV·head_dim)``:
+    the head count is no tile's minor dimension, so no head is padded and
+    the stacked pool can be updated in place (see :func:`_scan_stack`).
+    An int8 pool adds f32 ``<name>_scale`` siblings (symmetric, per block,
+    and per KV head where the leaf carries heads; MLA's latent leaves get
+    one scalar scale per block)."""
+    out: Dict[str, Any] = {}
+    for name, (shape, dt) in _layer_cache_struct(cfg, mixer, nb, bs,
+                                                 dtype).items():
+        if mixer == "gqa":
+            shape = (nb, bs, cfg.num_kv_heads * cfg.head_dim)
+        out[name] = (shape, dt)
+        if quantized:
+            out[name + "_scale"] = (
+                ((nb, cfg.num_kv_heads) if mixer == "gqa" else (nb,)),
+                jnp.float32)
+    return out
+
+
+def _pool_axes_one(cfg: ModelConfig, mixer: str, quantized: bool):
+    out: Dict[str, Any] = {}
+    for name, a in _cache_axes_one(cfg, mixer).items():
+        # the flat GQA leaf keeps "act_heads" on its last axis: under TP
+        # it shards in contiguous whole-head chunks when KV % tp == 0
+        out[name] = a[:3] if mixer == "gqa" else a
+        if quantized:
+            # scales shard with the KV-head axis under TP (or replicate
+            # when the leaf has no head axis, e.g. MLA latents)
+            out[name + "_scale"] = (("act_batch", "act_heads")
+                                    if "act_heads" in a else ("act_batch",))
+    return out
+
+
+def _pool_tree(cfg: ModelConfig, layer, stack):
+    """``{"stack": stacked layers, "first": [lone layers]}`` of a paged
+    pool, from ``layer(mixer)`` and ``stack(tree, n)``."""
+    plan = stack_plan(cfg)
+    c: Dict[str, Any] = {"stack": stack(layer(plan["mixer"]), plan["n"])}
+    if plan["first"]:
+        c["first"] = [layer(m) for m, _ in plan["first"]]
+    return c
+
+
 def make_paged_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
-                    dtype=jnp.bfloat16, abstract: bool = False):
+                    dtype=jnp.bfloat16, abstract: bool = False,
+                    kv_dtype: str = "bf16"):
     """Shared physical KV pool for paged decode.
 
-    Structurally this *is* a cache with ``batch == num_blocks`` and
-    ``capacity == block_size``: each batch row is one physical block, and
-    block tables map (sequence, logical block) -> row.  Every layer leaf
-    indexes rows identically, so one block id spans the whole stack and
-    allocation is accounted in token blocks, not per-layer bytes.
+    Each layer leaf is ``(num_blocks, block_size, ...)``: one row per
+    physical block, which block tables map (sequence, logical block) to.
+    The scanned layers' leaves are stacked ``(layers, num_blocks, ...)``;
+    a GQA position is stored flat, ``(…, block_size, KV·head_dim)``.
+    Every layer leaf indexes rows identically, so one block id spans the
+    whole stack and allocation is accounted in token blocks, not
+    per-layer bytes.  Decode carries the stacked pool through the layer
+    scan and updates it in place.  ``kv_dtype="int8"`` stores int8 block
+    data with f32 per-block scale leaves inside each layer dict, so scan
+    threading, donation, export/import and byte accounting treat scales
+    as ordinary pool leaves.
     """
     if not supports_paged_cache(cfg):
         raise ValueError("architecture has no position-sliceable KV cache")
-    return make_cache(cfg, num_blocks, block_size, dtype, abstract)
-
-
-def _quantized_layer_pool_struct(cfg: ModelConfig, mixer: str, nb: int,
-                                 bs: int):
-    """One layer of an int8 paged pool: the int8 data leaves plus f32
-    ``<name>_scale`` siblings (symmetric, per-block — and per-KV-head for
-    leaves that carry a head axis; MLA's latent leaves get one scalar
-    scale per block)."""
-    base = _layer_cache_struct(cfg, mixer, nb, bs, jnp.int8)
-    ax = _cache_axes_one(cfg, mixer)
-    out: Dict[str, Any] = {}
-    for name, leaf in base.items():
-        shape, _ = leaf
-        out[name] = leaf
-        if "act_heads" in ax[name]:
-            heads = shape[ax[name].index("act_heads")]
-            out[name + "_scale"] = ((nb, heads), jnp.float32)
-        else:
-            out[name + "_scale"] = ((nb,), jnp.float32)
-    return out
-
-
-def _quantized_pool_axes_one(cfg: ModelConfig, mixer: str):
-    ax = _cache_axes_one(cfg, mixer)
-    out: Dict[str, Any] = {}
-    for name, a in ax.items():
-        out[name] = a
-        # scales shard with the KV-head axis under TP (or replicate when
-        # the leaf has no head axis, e.g. MLA latents)
-        out[name + "_scale"] = (("act_batch", "act_heads")
-                                if "act_heads" in a else ("act_batch",))
-    return out
-
-
-def make_quantized_paged_pool(cfg: ModelConfig, num_blocks: int,
-                              block_size: int, abstract: bool = False):
-    """Int8 paged pool: same layer/stack layout as :func:`make_paged_pool`
-    but with int8 block data and f32 per-block scale leaves riding inside
-    each layer dict — so scan threading, donation, export/import and byte
-    accounting all treat scales as ordinary pool leaves."""
-    if not supports_paged_cache(cfg):
-        raise ValueError("architecture has no position-sliceable KV cache")
-    plan = stack_plan(cfg)
-    c: Dict[str, Any] = {}
-    if plan["first"]:
-        c["first"] = [_quantized_layer_pool_struct(cfg, m, num_blocks,
-                                                   block_size)
-                      for m, _ in plan["first"]]
-    c["stack"] = _stackc(
-        _quantized_layer_pool_struct(cfg, plan["mixer"], num_blocks,
-                                     block_size),
-        plan["n"])
-    return _materialize(c, abstract)
+    q = kv_dtype == "int8"
+    dt = jnp.int8 if q else dtype
+    return _materialize(_pool_tree(
+        cfg, lambda m: _layer_pool_struct(cfg, m, num_blocks, block_size,
+                                          dt, q),
+        _stackc), abstract)
 
 
 def paged_pool_axes(cfg: ModelConfig, kv_dtype: str = "bf16"):
-    """Logical sharding axes for a paged pool.  ``bf16`` pools share the
-    plain cache axes; ``int8`` pools add the ``*_scale`` leaves."""
-    if kv_dtype != "int8":
-        return cache_axes(cfg)
-    plan = stack_plan(cfg)
-    pre = ("layers",)
-    ax1 = _quantized_pool_axes_one(cfg, plan["mixer"])
-    c: Dict[str, Any] = {"stack": jax.tree.map(
-        lambda a: pre + a, ax1, is_leaf=lambda x: isinstance(x, tuple))}
-    if plan["first"]:
-        c["first"] = [_quantized_pool_axes_one(cfg, m)
-                      for m, _ in plan["first"]]
-    return c
+    """Logical sharding axes for a paged pool: the GQA leaves' three
+    ``("act_batch", "act_kvseq", "act_heads")`` after ``"layers"`` on the
+    stack; ``int8`` pools add the ``*_scale`` leaves."""
+    q = kv_dtype == "int8"
+    return _pool_tree(
+        cfg, lambda m: _pool_axes_one(cfg, m, q),
+        lambda t, n: jax.tree.map(lambda a: ("layers",) + a, t,
+                                  is_leaf=lambda x: isinstance(x, tuple)))
 
 
 def pad_cache(cfg: ModelConfig, cache, capacity: int):
@@ -355,34 +355,45 @@ def _scan_stack(cfg: ModelConfig, stack_p, x, positions, *, mixer, ffn,
                 adapter_ids=None):
     """Scan a homogeneous stacked layer group.  ``lora`` leaves carry the
     same leading layer axis as the stacked params, so the scan slices a
-    per-layer adapter stack alongside each layer's weights."""
+    per-layer adapter stack alongside each layer's weights.
+
+    A paged pool (``block_tables`` given) rides in the carry, whole and
+    stacked, and each layer reads and writes it at its own layer index:
+    the step updates the donated pool in place.  As scan ``xs``/``ys`` it
+    would be sliced per layer and written back into a second stacked
+    pool, which then has to be copied into the donated buffer."""
+    paged = cache is not None and block_tables is not None
     xs: Dict[str, Any] = {"p": stack_p}
-    if cache is not None:
+    if paged:
+        xs["layer"] = jnp.arange(jax.tree.leaves(stack_p)[0].shape[0])
+    elif cache is not None:
         xs["cache"] = cache
     if cross_cache is not None:
         xs["cross"] = cross_cache
     if lora is not None:
         xs["lora"] = lora
-    is_dec = "cross" in stack_p
 
     def body(carry, layer_in):
-        h, aux = carry
-        cl = layer_in.get("cache")
-        crl = layer_in.get("cross")
+        h, aux, pool = carry
         h, nc, ncross, a = apply_layer(
             cfg, layer_in["p"], h, positions, mixer=mixer, ffn=ffn,
-            mode=mode, cache=cl, lengths=lengths, causal=causal,
-            enc_out=enc_out, cross_cache=crl, block_tables=block_tables,
-            lora=layer_in.get("lora"), adapter_ids=adapter_ids)
+            mode=mode, cache=pool if paged else layer_in.get("cache"),
+            lengths=lengths, causal=causal, enc_out=enc_out,
+            cross_cache=layer_in.get("cross"), block_tables=block_tables,
+            layer=layer_in.get("layer"), lora=layer_in.get("lora"),
+            adapter_ids=adapter_ids)
         ys = {}
-        if nc is not None:
+        if nc is not None and not paged:
             ys["cache"] = nc
         if ncross is not None:
             ys["cross"] = ncross
-        return (h, aux + a), ys
+        return (h, aux + a, nc if paged else None), ys
 
     body = _maybe_remat(cfg, body, mode)
-    (x, aux), ys = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    (x, aux, pool), ys = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), cache if paged else None), xs)
+    if paged:
+        ys["cache"] = pool
     return x, aux, ys
 
 

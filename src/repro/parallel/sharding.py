@@ -115,8 +115,9 @@ def make_rules(kind: str, multi_pod: bool = False, **overrides) -> RuleSet:
         # engine's continuous batch is one replica's traffic), parameters
         # are pure-TP over "model" (no fsdp — a serving replica gains
         # nothing from gather-per-layer), attention is head-sharded so a
-        # paged pool leaf (num_blocks, block_size, KV, hd) shards on its
-        # KV-head axis and the host-side block tables are untouched, and
+        # paged pool leaf (num_blocks, block_size, KV·hd) shards on its
+        # flat KV-head axis (in whole heads when KV % tp == 0) and the
+        # host-side block tables are untouched, and
         # logits are replicated (one all-gather per step) so sampling is
         # identical on every device.
         r.update(

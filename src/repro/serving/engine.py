@@ -260,9 +260,8 @@ class InferenceEngine:
         # across micro-steps (no per-step resharding, no recompiles).
         # two axes trees: the *dense* cache axes for prefill and the
         # dense decode/verify steps (their cache trees never carry scale
-        # leaves), and the slots' axes for the paged steps (identical to
-        # the dense tree for bf16 pools; int8 pools add ``*_scale``
-        # leaves)
+        # leaves), and the slots' pool axes for the paged steps (GQA
+        # leaves flat on (KV·head_dim); int8 pools add ``*_scale`` leaves)
         dense_axes = M.cache_axes(cfg)
         cache_axes = self.slots._axes
         mk_jit = lambda f, **kw: sharding.sharded_jit(  # noqa: E731

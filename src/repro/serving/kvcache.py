@@ -8,10 +8,13 @@ Two storage layouts (README.md "Paged KV" section):
   (SSM/hybrid state, encoder-decoder, vision-prefixed).
 - ``BlockPool`` + ``PagedCacheSlots`` — vLLM-style paged layout: one
   shared physical pool of ``block_size``-token blocks
-  (``M.make_paged_pool``) plus per-slot block tables.  Blocks are
-  allocated on demand and ref-counted, so memory tracks *actual* sequence
-  lengths (not worst-case capacity) and the radix prefix cache shares
-  physical blocks with running requests instead of copying KV segments.
+  (``M.make_paged_pool``: stacked ``(layers, num_blocks, block_size,
+  KV·head_dim)`` leaves for GQA, which the decode step carries through
+  the layer scan and updates in place) plus per-slot block tables.
+  Blocks are allocated on demand and ref-counted, so memory tracks
+  *actual* sequence lengths (not worst-case capacity) and the radix
+  prefix cache shares physical blocks with running requests instead of
+  copying KV segments.
 
 ``BlockLedger`` is the admission-control account for the dense path (and
 the node budget of the prefix cache); the paged path accounts in real
@@ -256,8 +259,10 @@ class KVHandoff:
     Produced by :meth:`PagedCacheSlots.export_kv` on the prefill side:
     the finished prefill's physical blocks gathered to host memory in
     block-major layout (each cache leaf becomes ``(n_blocks, ...)`` with
-    the pool's block axis moved to the front).  Consumed by
-    :meth:`PagedCacheSlots.import_kv` on the decode side, which
+    the pool's block axis moved to the front, and a GQA position's KV
+    heads split out of the pool's flat leaf as the dense cache holds
+    them, so the payload does not depend on the pool's layout).
+    Consumed by :meth:`PagedCacheSlots.import_kv` on the decode side, which
     allocates fresh pool blocks and scatters the payload back — or, for
     a prefix the decode-side radix tree already holds, splices the
     shared blocks in place of re-uploading them.
@@ -429,26 +434,23 @@ class PagedCacheSlots:
         self.blocks_per_seq = -(-capacity // block_size)
         pool_tokens = (max_batch * capacity if pool_tokens is None
                        else pool_tokens)
-        if kv_dtype == "int8":
-            # ``pool_tokens`` is a bf16-byte-equivalent budget: int8
-            # blocks cost half the bytes, so the same budget buys twice
-            # the physical blocks — admission capacity doubles for free
-            num_blocks = 1 + max((pool_tokens * 2) // block_size,
-                                 self.blocks_per_seq)
-            self.pool = M.make_quantized_paged_pool(cfg, num_blocks,
-                                                    block_size)
-            self._axes = M.paged_pool_axes(cfg, "int8")
-        else:
-            num_blocks = 1 + max(pool_tokens // block_size,
-                                 self.blocks_per_seq)
-            self.pool = M.make_paged_pool(cfg, num_blocks, block_size,
-                                          dtype)
-            self._axes = M.cache_axes(cfg)
+        # ``pool_tokens`` is a bf16-byte-equivalent budget: int8 blocks
+        # cost half the bytes, so the same budget buys twice the physical
+        # blocks — admission capacity doubles for free
+        per_token = 2 if kv_dtype == "int8" else 1
+        num_blocks = 1 + max((pool_tokens * per_token) // block_size,
+                             self.blocks_per_seq)
+        self.pool = M.make_paged_pool(cfg, num_blocks, block_size, dtype,
+                                      kv_dtype=kv_dtype)
+        self._axes = M.paged_pool_axes(cfg, kv_dtype)
+        # the prefill caches written into the pool keep the dense layout
+        self._cache_axes = M.cache_axes(cfg)
         if mesh is not None:
             # a pool leaf is (num_blocks, block_size, ...) in the cache's
             # (act_batch, act_kvseq, ...) axis slots; under serving_tp
             # both map to None, so the pool shards exactly on the KV-head
-            # axis (GQA) or stays replicated (MLA latent) — block ids,
+            # axis (GQA's flat KV·head_dim axis, in whole heads when
+            # KV % tp == 0) or stays replicated (MLA latent) — block ids,
             # tables, and all host-side accounting are layout-invariant
             self.pool = jax.device_put(
                 self.pool,
@@ -562,33 +564,46 @@ class PagedCacheSlots:
         self._touch_tables()
 
     # ------------------------------------------------------------ prefill
+    def _blocked(self, src, ax, nblk):
+        """A single-sequence prefill cache leaf (…, 1, S, *inner), padded
+        or cut to ``nblk`` whole blocks and split into them: (…, nblk,
+        block_size, *inner)."""
+        bi, ki = ax.index("act_batch"), ax.index("act_kvseq")
+        span = nblk * self.block_size
+        if src.shape[ki] < span:
+            pads = [(0, 0)] * src.ndim
+            pads[ki] = (0, span - src.shape[ki])
+            src = jnp.pad(src, pads)
+        idx = [slice(None)] * src.ndim
+        idx[ki] = slice(0, span)
+        src = src[tuple(idx)]
+        shape = list(src.shape)
+        shape[bi:ki + 1] = [nblk, self.block_size]
+        return src.reshape(shape)
+
+    @staticmethod
+    def _write_blocks(dst, src, ax, ids):
+        """``dst`` with the blocks ``ids`` set to ``src`` (…, len(ids),
+        block_size, *inner), reshaped at the write to the pool leaf's
+        own layout (a GQA position's heads flat)."""
+        bi = ax.index("act_batch")
+        src = jnp.moveaxis(src, bi, 0)
+        d = jnp.moveaxis(dst, bi, 0)
+        src = src.reshape(src.shape[:bi + 2] + d.shape[bi + 2:])
+        return jnp.moveaxis(d.at[ids].set(src.astype(dst.dtype)), 0, bi)
+
     def _scatter_impl(self, pool, prefill_cache, ids):
         """Write a single-sequence prefill cache (1, S, ...) into the
         ``len(ids)`` physical blocks named by ``ids``."""
         nblk = ids.shape[0]
-        blk = self.block_size
 
         def one(leaves, ax):
             dst, src = leaves
-            bi = ax.index("act_batch")
-            ki = ax.index("act_kvseq")
-            src = src.astype(dst.dtype)
-            span = nblk * blk
-            if src.shape[ki] < span:
-                pads = [(0, 0)] * src.ndim
-                pads[ki] = (0, span - src.shape[ki])
-                src = jnp.pad(src, pads)
-            idx = [slice(None)] * src.ndim
-            idx[ki] = slice(0, span)
-            src = src[tuple(idx)]
-            shape = list(src.shape)
-            shape[bi:ki + 1] = [nblk, blk]
-            src = src.reshape(shape)
-            d = jnp.moveaxis(dst, bi, 0)
-            s = jnp.moveaxis(src, bi, 0)
-            return jnp.moveaxis(d.at[ids].set(s), 0, bi)
+            return self._write_blocks(
+                dst, self._blocked(src.astype(dst.dtype), ax, nblk), ax,
+                ids)
 
-        out = tree_multi(one, [pool, prefill_cache], self._axes)
+        out = tree_multi(one, [pool, prefill_cache], self._cache_axes)
         return constrain_cache(out, self._axes)
 
     def _scatter_impl_q(self, pool, prefill_cache, ids):
@@ -599,63 +614,39 @@ class PagedCacheSlots:
         the leaf has a head axis).  The prefill cache carries no scale
         leaves — they are derived here."""
         nblk = ids.shape[0]
-        blk = self.block_size
 
         def qone(dst, sc, src, ax, sc_ax):
-            bi = ax.index("act_batch")
-            ki = ax.index("act_kvseq")
-            span = nblk * blk
-            src = src.astype(jnp.float32)
-            if src.shape[ki] < span:
-                pads = [(0, 0)] * src.ndim
-                pads[ki] = (0, span - src.shape[ki])
-                src = jnp.pad(src, pads)
-            idx = [slice(None)] * src.ndim
-            idx[ki] = slice(0, span)
-            src = src[tuple(idx)]
-            shape = list(src.shape)
-            shape[bi:ki + 1] = [nblk, blk]
-            src = src.reshape(shape)
-            # after the reshape the act_batch slot is the block axis and
+            src = self._blocked(src.astype(jnp.float32), ax, nblk)
+            # after the split the act_batch slot is the block axis and
             # the act_kvseq slot the in-block position; reduce the scale
             # over every axis the scale leaf does not keep
-            labels = list(ax)
-            labels[bi] = "act_batch"
-            labels[ki] = None
-            red = tuple(i for i, a in enumerate(labels)
-                        if a not in ("layers", "act_batch", "act_heads"))
+            ki = ax.index("act_kvseq")
+            red = tuple(i for i, a in enumerate(ax) if i == ki or a not in
+                        ("layers", "act_batch", "act_heads"))
             s_kd = jnp.max(jnp.abs(src), axis=red, keepdims=True) / 127.0
             q = jnp.clip(jnp.round(src / jnp.maximum(s_kd, 1e-12)),
-                         -127, 127).astype(dst.dtype)
-            scale = jnp.squeeze(s_kd, axis=red)
-            d_new = jnp.moveaxis(
-                jnp.moveaxis(dst, bi, 0).at[ids].set(
-                    jnp.moveaxis(q, bi, 0)), 0, bi)
-            sbi = sc_ax.index("act_batch")
-            s_new = jnp.moveaxis(
-                jnp.moveaxis(sc, sbi, 0).at[ids].set(
-                    jnp.moveaxis(scale, sbi, 0)), 0, sbi)
-            return d_new, s_new
+                         -127, 127)
+            return (self._write_blocks(dst, q, ax, ids),
+                    self._write_blocks(sc, jnp.squeeze(s_kd, axis=red),
+                                       sc_ax, ids))
 
-        def walk(pl, pc, ax):
+        def walk(pl, pc, ax, dax):
             if isinstance(pl, dict):
                 if any(k.endswith("_scale") for k in pl):
                     out: Dict[str, Any] = {}
                     for k in pl:
                         if k.endswith("_scale"):
                             continue
-                        d_new, s_new = qone(pl[k], pl[k + "_scale"],
-                                            pc[k], ax[k],
-                                            ax[k + "_scale"])
-                        out[k] = d_new
-                        out[k + "_scale"] = s_new
+                        out[k], out[k + "_scale"] = qone(
+                            pl[k], pl[k + "_scale"], pc[k], dax[k],
+                            ax[k + "_scale"])
                     return out
-                return {k: walk(pl[k], pc[k], ax[k]) for k in pl}
+                return {k: walk(pl[k], pc[k], ax[k], dax[k]) for k in pl}
             if isinstance(pl, list):
-                return [walk(p, c, a) for p, c, a in zip(pl, pc, ax)]
+                return [walk(*a) for a in zip(pl, pc, ax, dax)]
             raise TypeError("int8 pool leaf without a scale sibling")
 
-        out = walk(pool, prefill_cache, self._axes)
+        out = walk(pool, prefill_cache, self._axes, self._cache_axes)
         return constrain_cache(out, self._axes)
 
     def insert_prefill(self, slot: int, prefill_cache, length: int):
@@ -673,11 +664,15 @@ class PagedCacheSlots:
     # ------------------------------------------------------------ handoff
     def _gather_impl(self, pool, ids):
         """Read the ``len(ids)`` physical blocks named by ``ids`` out of
-        the pool, block axis first — the exact inverse layout of
-        :meth:`_scatter_blocks_impl`."""
+        the pool, block axis first and each position's KV heads split out
+        of the flat GQA leaf, as the dense cache holds them — the exact
+        inverse of :meth:`_scatter_blocks_impl`."""
         def one(arr, ax):
-            bi = ax.index("act_batch")
-            return jnp.moveaxis(arr, bi, 0)[ids]
+            out = jnp.moveaxis(arr, ax.index("act_batch"), 0)[ids]
+            if "act_kvseq" in ax and ax[-1] == "act_heads":
+                out = out.reshape(out.shape[:-1]
+                                  + (self.cfg.num_kv_heads, -1))
+            return out
 
         return tree_walk(one, pool, self._axes)
 
@@ -686,9 +681,8 @@ class PagedCacheSlots:
         physical blocks named by ``ids``."""
         def one(leaves, ax):
             dst, src = leaves
-            bi = ax.index("act_batch")
-            d = jnp.moveaxis(dst, bi, 0)
-            return jnp.moveaxis(d.at[ids].set(src.astype(dst.dtype)), 0, bi)
+            return self._write_blocks(
+                dst, jnp.moveaxis(src, 0, ax.index("act_batch")), ax, ids)
 
         out = tree_multi(one, [pool, blocks], self._axes)
         return constrain_cache(out, self._axes)

@@ -140,6 +140,44 @@ def test_paged_equals_dense_outputs(served):
     assert outs[True] == outs[False]
 
 
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["mha", "gqa"])
+def test_paged_steps_bit_identical_to_dense(kv_heads):
+    """Decode and verify steps over a permuted block table give the
+    dense cache's logits bit for bit, and leave the pool holding what
+    the dense cache holds, when both reduce over the same length (dense
+    capacity = table width x block size)."""
+    cfg = scaled_down(get_config("qwen1.5-4b"), num_layers=2, d_model=64,
+                      d_ff=128, vocab_size=128, num_heads=4,
+                      num_kv_heads=kv_heads, head_dim=16)
+    params = M.init(cfg, jax.random.PRNGKey(3))
+    B, W, bs = 3, 5, 4
+    rng = np.random.default_rng(7)
+    nb = 1 + B * W + 4
+    tables = jnp.asarray(rng.permutation(np.arange(1, nb))[:B * W]
+                         .reshape(B, W).astype(np.int32))
+    pool = M.make_paged_pool(cfg, nb, bs)
+    cache = M.make_cache(cfg, B, W * bs)
+    lengths = jnp.asarray([3, 9, 1], jnp.int32)
+    paged = jax.jit(lambda t, p, l: M.decode_step_paged(
+        cfg, params, t, p, tables, l))
+    dense = jax.jit(lambda t, c, l: M.decode_step(cfg, params, t, c, l))
+    for _ in range(4):
+        lengths = lengths + 1
+        tok = jnp.asarray(rng.integers(1, 128, (B, 1)), jnp.int32)
+        got, pool = paged(tok, pool, lengths)
+        want, cache = dense(tok, cache, lengths)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+    lengths = lengths + 3
+    tok = jnp.asarray(rng.integers(1, 128, (B, 3)), jnp.int32)
+    got, pool = M.verify_step_paged(cfg, params, tok, pool, tables, lengths)
+    want, cache = M.verify_step(cfg, params, tok, cache, lengths)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    for name in ("k", "v"):
+        rows = np.asarray(pool["stack"][name])[:, np.asarray(tables)]
+        assert np.array_equal(rows.reshape(cache["stack"][name].shape),
+                              np.asarray(cache["stack"][name]))
+
+
 def test_paged_mla_engine_matches_reference():
     """MLA caches (latent + rope leaves) page the same way."""
     cfg = scaled_down(get_config("deepseek-v2-lite-16b"), num_layers=2,

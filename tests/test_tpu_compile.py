@@ -12,6 +12,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library, and pytest
 workers import every test file.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -76,25 +79,74 @@ def test_paged_kernel_compiles_for_v5e(one_chip, kernel, arch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_full_width_paged_decode_step_fits_one_v5e(one_chip):
-    """qwen1.5-4b at its published widths, bf16 weights, the smoke's
-    pool: the fused decode step the engine runs must fit one chip."""
+def _full_width_step(one_chip, max_batch, tail):
+    """Compile the engine's greedy paged step for qwen1.5-4b at its
+    published widths, bf16 weights, a pool of ``max_batch`` slots of
+    ``CAPACITY``: the decode step for a 1-token tail, the speculative
+    verify step otherwise.  Returns (config, pool, compiled)."""
     cfg = get_config("qwen1.5-4b")
-    nb = 1 + MAX_BATCH * CAPACITY // BLOCK
+    nb = 1 + max_batch * CAPACITY // BLOCK
     params = abstract_params(M.model_specs(cfg), jnp.bfloat16)
     pool = M.make_paged_pool(cfg, nb, BLOCK, abstract=True)
+    fn = M.decode_step_paged if tail == 1 else M.verify_step_paged
 
     def step(p, tokens, pool, block_tables, lengths):
-        logits, pool = M.decode_step_paged(cfg, p, tokens, pool,
-                                           block_tables, lengths)
+        logits, pool = fn(cfg, p, tokens, pool, block_tables, lengths)
         return jnp.argmax(logits, -1).astype(jnp.int32), pool
 
     args = _on(one_chip, (
-        params, jax.ShapeDtypeStruct((MAX_BATCH, 1), jnp.int32), pool,
-        jax.ShapeDtypeStruct((MAX_BATCH, CAPACITY // BLOCK), jnp.int32),
-        jax.ShapeDtypeStruct((MAX_BATCH,), jnp.int32)))
+        params, jax.ShapeDtypeStruct((max_batch, tail), jnp.int32), pool,
+        jax.ShapeDtypeStruct((max_batch, CAPACITY // BLOCK), jnp.int32),
+        jax.ShapeDtypeStruct((max_batch,), jnp.int32)))
     compiled = jax.jit(step, donate_argnums=(2,)).lower(*args).compile()
+    return cfg, pool, compiled
+
+
+def _results(hlo):
+    """(dtype, element count, layout, op, fused) of every array
+    instruction in compiled HLO text.  ``fused`` marks an instruction of
+    a fusion's body, which is not materialized on its own; the layout
+    carries the memory space: ``S(1)`` is the chip's on-core VMEM, none
+    is HBM."""
+    bodies = set(re.findall(r"calls=(%[\w.-]+)", hlo))
+    fused = False
+    for line in hlo.splitlines():
+        if not line.startswith(" "):
+            fused = line.split(" ")[0] in bodies
+            continue
+        m = re.search(r"= (\w+)\[([\d,]*)\](\{[^}]*\})? ([\w-]+)\(", line)
+        if m:
+            dims = [int(d) for d in m.group(2).split(",") if d]
+            yield (m.group(1), math.prod(dims), m.group(3) or "",
+                   m.group(4), fused)
+
+
+def test_full_width_paged_decode_step_fits_one_v5e(one_chip):
+    """qwen1.5-4b at its published widths, bf16 weights, the smoke's
+    pool: the fused decode step the engine runs must fit one chip."""
+    _, _, compiled = _full_width_step(one_chip, MAX_BATCH, 1)
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes > 7.8e9       # the bf16 weights
     assert used < V5E_HBM_LIMIT, used
+
+
+@pytest.mark.parametrize("tail", [1, 5], ids=["decode", "verify"])
+def test_full_width_paged_step_updates_pool_in_place(one_chip, tail):
+    """At the benchmark's 8 slots the decode step (and the speculative
+    verify step with a 5-token tail) writes the donated pool in place:
+    no second pool among the temporaries, no copy or
+    dynamic-update-slice of the whole stacked pool, and no f32 copy of
+    the gathered KV in HBM.  (The compiler still upcasts the gathered
+    KV for attention's multiply-reduce, into VMEM.)"""
+    B = 8
+    cfg, pool, compiled = _full_width_step(one_chip, B, tail)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.25e9, mem.temp_size_in_bytes
+    pool_elems = {math.prod(leaf.shape) for leaf in jax.tree.leaves(pool)}
+    gathered = B * CAPACITY * cfg.num_kv_heads * cfg.head_dim
+    for dtype, n, layout, op, fused in _results(compiled.as_text()):
+        assert not (n in pool_elems
+                    and op in ("copy", "dynamic-update-slice")), op
+        assert fused or not (dtype == "f32" and n == gathered
+                             and "S(1)" not in layout), op
