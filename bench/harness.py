@@ -5,13 +5,17 @@ gateway, a warm-up of exactly the shapes the mix can produce, then a
 measured window of ``--seconds`` in which every due request enters
 through ``Gateway.completion(..., run=False)`` and the loop between
 arrivals is ``InferenceEngine.step()``.  After the window the served
-tokens of a sample of requests are compared with the plain reference
-(``bench/reference.py``).
+tokens of a sample of requests are compared with the plain reference.
 
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file found by its name in ``BENCHMARK.json``:
 ``bench/configs/<config>.json``, ``bench/mixes/<traffic>.json`` and
-``bench/metrics/<metric>.py``.
+``bench/metrics/<metric>.py``.  Everything that depends on the model's
+architecture is in the module of the family that the configuration
+names, ``bench/families/<family>.py`` (:func:`load_family`): the
+program's model configuration, the weights' layout, the plain
+reference's forward pass and the required work.  This module knows no
+architecture.
 """
 from __future__ import annotations
 
@@ -48,6 +52,7 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     readers: Dict[str, Callable]
+    family: object
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -76,9 +81,34 @@ def load_reader(name: str, root: str = ROOT) -> Callable:
     return mod.read
 
 
+def load_family(c: dict, root: str = ROOT):
+    """The module of the model family that configuration ``c`` names
+    under ``"family"``: ``bench/families/<family>.py`` under ``root``.
+    It gives ``program_config``, ``layout`` (and ``DRAWS`` where it has
+    kinds of weight of its own), ``position_stats``, ``decode_flops``,
+    ``prefill_flops`` and ``decode_step_work``.  There is no default."""
+    where = os.path.join(root, "bench", "families")
+    if "family" not in c:
+        raise KeyError(f"configuration {c.get('name')!r} has no "
+                       f"\"family\": it names the module "
+                       f"{os.path.join(where, '<family>.py')}")
+    path = os.path.join(where, c["family"] + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"configuration {c.get('name')!r} names "
+                                f"family {c['family']!r}: no file {path}")
+    key = "bench_family_" + c["family"]
+    mod = sys.modules.get(key)
+    if mod is None or mod.__file__ != path:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        spec.loader.exec_module(mod)
+    return mod
+
+
 def resolve(workload: str, root: str = ROOT) -> Cell:
-    """The cell named ``workload`` with its configuration, mix and
-    readers, found by name."""
+    """The cell named ``workload`` with its configuration, mix, readers
+    and model family, found by name."""
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -94,27 +124,11 @@ def resolve(workload: str, root: str = ROOT) -> Cell:
     return Cell(workload, w["chips"], config, mix,
                 [m for m in bench["end_to_end"] if _applies(m, workload)],
                 per_layer,
-                {m["name"]: load_reader(m["name"], root) for m in per_layer})
+                {m["name"]: load_reader(m["name"], root) for m in per_layer},
+                load_family(config, root))
 
 
 # -------------------------------------------------------- the program
-def program_config(c: dict):
-    """The program's model configuration, set to the values the
-    configuration file states."""
-    from repro.configs import get_config
-    base = get_config(c["program_arch"])
-    return dataclasses.replace(
-        base, num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"],
-        head_dim=c.get("head_dim") or c["hidden_size"]
-        // c["num_attention_heads"],
-        qkv_bias=bool(c.get("attention_bias")), rope_theta=c["rope_theta"],
-        norm_eps=c["rms_norm_eps"],
-        tie_embeddings=bool(c.get("tie_word_embeddings")))
-
-
 @dataclasses.dataclass
 class System:
     cfg: object
@@ -126,9 +140,9 @@ class System:
     mesh: object
 
 
-def build(c: dict, seed: int, devices) -> System:
-    """Weights from ``seed`` (sharded as made where ``tp`` > 1), the
-    engine, and the gateway in front of it."""
+def build(family, c: dict, seed: int, devices) -> System:
+    """Weights from ``seed`` in the layout of ``family`` (sharded as made
+    where ``tp`` > 1), the engine, and the gateway in front of it."""
     import jax
     from repro.core.gateway import Gateway, ModelEntry
     from repro.launch.mesh import make_mesh
@@ -137,8 +151,9 @@ def build(c: dict, seed: int, devices) -> System:
     from repro.serving.engine import InferenceEngine
     from repro.serving.scheduler import SchedulerConfig
 
-    pcfg = program_config(c)
-    W.check_layout(c, jax.eval_shape(
+    pcfg = family.program_config(c)
+    lay = family.layout(c)
+    W.check_layout(lay, jax.eval_shape(
         lambda k: M.init(pcfg, k), jax.random.PRNGKey(0)))
     mesh = shardings = None
     if c["tp"] > 1:
@@ -146,7 +161,8 @@ def build(c: dict, seed: int, devices) -> System:
         shardings = sharding.tree_shardings(
             M.model_param_axes(pcfg), mesh,
             sharding.make_rules("serving_tp"))
-    params = W.make(c, seed, shardings)
+    params = W.make(lay, seed, shardings,
+                    draws=getattr(family, "DRAWS", None))
     eng = InferenceEngine(pcfg, params, max_batch=c["max_batch"],
                           capacity=c["capacity"],
                           pool_tokens=c["pool_tokens"], mesh=mesh,
@@ -224,6 +240,7 @@ class Run:
     """What one run saw; the per-layer readers read it."""
     cell: str
     config: dict
+    family: object
     mix: dict
     chips: int
     peaks: dict
@@ -362,11 +379,12 @@ def sample(records: List[Record], check: dict, seed: int) -> List[Record]:
     return chosen
 
 
-def compare(c: dict, params, chosen: List[Record], rows: int,
+def compare(family, c: dict, params, chosen: List[Record], rows: int,
             control: bool = False) -> dict:
-    """Widest gap by which a served token's reference logit lies below
-    the reference's best, over the sample; with ``control``, also the
-    same gap for the tokens the int8 control puts first."""
+    """Widest gap by which a served token's reference logit (``family``'s
+    ``position_stats``) lies below the reference's best, over the
+    sample; with ``control``, also the same gap for the tokens the int8
+    control puts first."""
     seqs = [(r.prompt, r.tokens) for r in chosen]
     seqs += [([0], [0])] * (rows - len(seqs))
     tokens, target, mask = reference.pack(seqs)
@@ -374,10 +392,10 @@ def compare(c: dict, params, chosen: List[Record], rows: int,
     out = {"served_tokens": int(mask.sum())}
     alt = None
     if control:
-        _, _, _, alt = reference.position_stats(c, params, tokens, target,
-                                                precision="int8")
-    mx, at, at_alt, _ = reference.position_stats(c, params, tokens, target,
-                                                 alt=alt)
+        _, _, _, alt = family.position_stats(c, params, tokens, target,
+                                             precision="int8")
+    mx, at, at_alt, _ = family.position_stats(c, params, tokens, target,
+                                              alt=alt)
     # with nothing compared the served-token count fails the check
     out["logit_gap_max"] = float(np.max((mx - at)[mask])) if mask.any() \
         else 0.0
@@ -406,7 +424,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     (tests only) is called with the built system before the window."""
     c, mix = cell.config, cell.mix
     counter = CompileCounter()
-    sys_ = build(c, seed, devices)
+    sys_ = build(cell.family, c, seed, devices)
     warm_up(sys_, mix, c["vocab_size"])
     reqs = traffic.generate(mix, seconds=seconds, seed=seed,
                             vocab_size=c["vocab_size"],
@@ -421,8 +439,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     peak_bytes = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                      for d in used)
     eng = sys_.engine
-    run = Run(cell.name, c, mix, cell.chips, peaks, out["t0"], out["t1"],
-              out["records"], out["ticks"], c["max_batch"],
+    run = Run(cell.name, c, cell.family, mix, cell.chips, peaks, out["t0"],
+              out["t1"], out["records"], out["ticks"], c["max_batch"],
               eng.kv_stats()["kv_blocks_total"],
               setup_s=out["t0"] - t_start, t_end=t_end)
     log(f"bench: compiles_in_window={counter.count} "
@@ -460,8 +478,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     gc.collect()
     chosen = sample(run.records, mix["check"], seed)
     t_ref = time.monotonic()
-    cmp_ = compare(c, sys_.params, chosen, mix["check"]["requests"],
-                   control=control)
+    cmp_ = compare(cell.family, c, sys_.params, chosen,
+                   mix["check"]["requests"], control=control)
     log(f"bench: reference over {len(chosen)} requests, "
         f"{cmp_['served_tokens']} served tokens, in "
         f"{time.monotonic() - t_ref:.1f}s")

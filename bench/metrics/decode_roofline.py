@@ -1,7 +1,8 @@
 """Share of the roofline reached by the decode program, per chip: the
-least time the chip needs for a decode step's required work (bench/
-work.py: FLOPs, and weight bytes plus the live tokens' KV bytes), over
-the step's device time.  The bound that sets it goes to run.notes."""
+least time the chip needs for a decode step's required work (the
+family's ``decode_step_work``: FLOPs, and weight bytes plus the live
+tokens' cached bytes), over the step's device time.  The bound that
+sets it goes to run.notes."""
 import stats
 import work
 
@@ -13,13 +14,10 @@ def read(run):
     ticks = stats.in_window(run)
     if not d["calls"] or not ticks:
         return None
-    c, tp = run.config, run.config["tp"]
     running = sum(k[1] for k in ticks) / len(ticks)
     live = sum(k[3] for k in ticks) / len(ticks)
-    flops = (running * 2 * (work.body_params(c) + work.head_params(c))
-             + live * work.attn_flops(c, 1)) / tp
-    nbytes = ((work.body_params(c) + work.head_params(c)) * work.BF16
-              + work.kv_bytes_per_token(c) * live) / tp
+    flops, nbytes = run.family.decode_step_work(run.config, running, live,
+                                                run.config["tp"])
     t, bound = work.roofline_seconds(flops, nbytes, run.peaks)
     run.notes["decode_roofline_bound"] = bound
     return 100.0 * t / (d["device_s"] / d["calls"])

@@ -5,8 +5,6 @@ from typing import List, Optional
 
 import numpy as np
 
-import work
-
 
 def pct(xs, q) -> Optional[float]:
     xs = [x for x in xs if x is not None]
@@ -50,19 +48,19 @@ def in_window(run, t0=None, t1=None):
 def window_flops(run) -> float:
     """Model FLOPs of every token the window processed: prompts whose
     prefill started in it (one-shot chunk and streamed tail) and every
-    token generated in it."""
-    c = run.config
+    token generated in it, counted by the configuration's family."""
+    c, f = run.config, run.family
     total = 0.0
     for r in run.records:
         if r.prefill_start is not None and run.t0 <= r.prefill_start \
                 <= run.t1:
             chunk = min(r.n_prompt, run.prefill_chunk)
-            total += work.prefill_flops(c, chunk)
-            total += sum(work.decode_flops(c, n)
+            total += f.prefill_flops(c, chunk)
+            total += sum(f.decode_flops(c, n)
                          for n in range(chunk + 1, r.n_prompt + 1))
         for i, t in enumerate(r.token_times):
             if i and run.t0 <= t <= run.t1:
-                total += work.decode_flops(c, r.n_prompt + i)
+                total += f.decode_flops(c, r.n_prompt + i)
     return total
 
 

@@ -48,7 +48,7 @@ def main(argv=None) -> None:
         sys.exit(f"no TPU: JAX sees {devices[0].platform} devices")
     bench_run.enable_cache(jax)
     c = cell.config
-    sys_ = harness.build(c, args.seed, devices)
+    sys_ = harness.build(cell.family, c, args.seed, devices)
     harness.warm_up(sys_, cell.mix, c["vocab_size"])
     eng = sys_.engine
     points = []

@@ -29,7 +29,8 @@ MIX = dict(tiny.CLOSED, output={"dist": "uniform", "min": 20, "max": 40},
 def _run(fault=None, control=False):
     c = tiny.config(vocab_size=8192, hidden_size=128, num_hidden_layers=4,
                     check={"logit_gap_limit": LIMIT})
-    cell = harness.Cell("tiny", 1, c, MIX, [], [], {})
+    cell = harness.Cell("tiny", 1, c, MIX, [], [], {},
+                        harness.load_family(c))
     return harness.run_cell(cell, seed=SEED, seconds=2.0, trace=False,
                             devices=jax.devices(),
                             peaks=PEAKS["TPU v5 lite"],

@@ -6,9 +6,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
 import reference
 import tiny
 import weights as W
+
+G = harness.load_family(tiny.CONFIG)
 
 
 @pytest.fixture(scope="module")
@@ -16,12 +19,11 @@ def served():
     from repro.models import model as M
     from repro.serving.engine import InferenceEngine, Request
     from repro.serving.scheduler import SchedulerConfig
-    import harness
     c = tiny.config(vocab_size=300)
-    pcfg = harness.program_config(c)
-    W.check_layout(c, jax.eval_shape(lambda k: M.init(pcfg, k),
-                                     jax.random.PRNGKey(0)))
-    params = W.make(c, 2 ** 35 + 11, dtype=jnp.float32)
+    pcfg = G.program_config(c)
+    W.check_layout(G.layout(c), jax.eval_shape(lambda k: M.init(pcfg, k),
+                                               jax.random.PRNGKey(0)))
+    params = W.make(G.layout(c), 2 ** 35 + 11, dtype=jnp.float32)
     eng = InferenceEngine(pcfg, params, max_batch=2, capacity=128,
                           sched=SchedulerConfig(prefill_chunk=16))
     rng = np.random.default_rng(0)
@@ -38,7 +40,7 @@ def served():
 def test_served_tokens_are_the_reference_argmax(served):
     c, params, seqs = served
     tokens, target, mask = reference.pack(seqs)
-    mx, at, _, arg = reference.position_stats(c, params, tokens, target)
+    mx, at, _, arg = G.position_stats(c, params, tokens, target)
     gaps = (mx - at)[mask]
     # f32 weights on both sides: only summation order differs
     assert gaps.max() < 1e-4, gaps.max()
@@ -48,9 +50,8 @@ def test_served_tokens_are_the_reference_argmax(served):
 def test_int8_control_departs_from_the_reference(served):
     c, params, seqs = served
     tokens, target, mask = reference.pack(seqs)
-    r32 = reference.position_stats(c, params, tokens, target)
-    r8 = reference.position_stats(c, params, tokens, target,
-                                  precision="int8")
+    r32 = G.position_stats(c, params, tokens, target)
+    r8 = G.position_stats(c, params, tokens, target, precision="int8")
     assert np.abs(r8[0] - r32[0])[mask].max() > 1e-3
 
 

@@ -180,7 +180,7 @@ def test_a_recorded_cpu_trace_of_one_engine_step(tmp_path):
     from tiny import CONFIG
     from repro.serving.engine import InferenceEngine, Request
     from repro.models import model as M
-    cfg = harness.program_config(CONFIG)
+    cfg = harness.load_family(CONFIG).program_config(CONFIG)
     params = M.init(cfg, jax.random.PRNGKey(0))
     eng = InferenceEngine(cfg, params, max_batch=2, capacity=64)
     assert eng.paged
@@ -222,7 +222,7 @@ def test_scopes_in_the_compiled_decode_program():
     from tiny import CONFIG
     from repro.serving.engine import InferenceEngine
     from repro.models import model as M
-    cfg = harness.program_config(CONFIG)
+    cfg = harness.load_family(CONFIG).program_config(CONFIG)
     params = M.init(cfg, jax.random.PRNGKey(0))
     eng = InferenceEngine(cfg, params, max_batch=2, capacity=64)
     B = eng.slots.B

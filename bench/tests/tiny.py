@@ -9,6 +9,7 @@ ROOT = os.path.dirname(BENCH)
 
 CONFIG = {
     "name": "tiny",
+    "family": "gqa_decoder",
     "program_arch": "qwen1.5-4b",
     "hidden_size": 64,
     "intermediate_size": 128,

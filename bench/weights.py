@@ -1,11 +1,12 @@
 """Random serving weights from a seed, made on the device in one jit.
 
-The tree is laid out as the program loads a dense GQA decoder (layers
-stacked on a leading axis); :func:`check_layout` compares it with the
-program's own shapes before anything runs.  Values are the benchmark's:
-matrices are normal with variance 1/fan_in, the embedding is standard
-normal, norm scales scatter around 1 and the QKV biases are large
-enough that leaving one out moves every logit.
+The tree's layout, ``{path: (shape, kind)}``, is the model family's
+(``layout`` in ``bench/families/<family>.py``); :func:`check_layout`
+compares it with the program's own shapes before anything runs.
+Values are the benchmark's: matrices are normal with variance 1/fan_in,
+the embedding is standard normal, norm scales scatter around 1 and
+biases are large enough that leaving one out moves every logit.  A
+family whose weights need another kind of draw gives it in ``draws``.
 """
 from __future__ import annotations
 
@@ -17,32 +18,6 @@ VOCAB_ALIGN = 256
 
 def vocab_padded(c) -> int:
     return -(-c["vocab_size"] // VOCAB_ALIGN) * VOCAB_ALIGN
-
-
-def layout(c):
-    """{path: (shape, kind)} of every weight; ``kind`` picks the draw."""
-    d, L = c["hidden_size"], c["num_hidden_layers"]
-    h, kv = c["num_attention_heads"], c["num_key_value_heads"]
-    hd = c.get("head_dim") or d // h
-    ff, vp = c["intermediate_size"], vocab_padded(c)
-    out = {
-        ("embed", "embed"): ((vp, d), "embed"),
-        ("embed", "unembed"): ((d, vp), "matrix"),
-        ("ln_f", "scale"): ((d,), "scale"),
-        ("stack", "ln1", "scale"): ((L, d), "scale"),
-        ("stack", "ln2", "scale"): ((L, d), "scale"),
-        ("stack", "mixer", "wq"): ((L, d, h * hd), "matrix"),
-        ("stack", "mixer", "wk"): ((L, d, kv * hd), "matrix"),
-        ("stack", "mixer", "wv"): ((L, d, kv * hd), "matrix"),
-        ("stack", "mixer", "wo"): ((L, h * hd, d), "matrix"),
-        ("stack", "mlp", "gate"): ((L, d, ff), "matrix"),
-        ("stack", "mlp", "up"): ((L, d, ff), "matrix"),
-        ("stack", "mlp", "down"): ((L, ff, d), "matrix"),
-    }
-    if c.get("attention_bias"):
-        for n, w in (("bq", h), ("bk", kv), ("bv", kv)):
-            out[("stack", "mixer", n)] = ((L, w * hd), "bias")
-    return out
 
 
 def _nest(flat):
@@ -62,7 +37,9 @@ def seed_key(seed: int):
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def _draw(key, shape, kind, dtype):
+def _draw(key, shape, kind, dtype, draws):
+    if kind in draws:
+        return draws[kind](key, shape, dtype)
     if kind == "embed":
         return jax.random.normal(key, shape, dtype)
     if kind == "matrix":
@@ -75,26 +52,29 @@ def _draw(key, shape, kind, dtype):
     raise ValueError(kind)
 
 
-def make(c, seed: int, shardings=None, dtype=jnp.bfloat16):
-    """The whole tree in ``dtype``, made by one jitted call from
-    ``seed``; ``shardings`` (a tree like the result) places each leaf as
-    it is made, so no device ever holds more than its share."""
-    lay = layout(c)
+def make(lay, seed: int, shardings=None, dtype=jnp.bfloat16, draws=None):
+    """The whole tree of layout ``lay`` in ``dtype``, made by one jitted
+    call from ``seed``; ``shardings`` (a tree like the result) places
+    each leaf as it is made, so no device ever holds more than its
+    share.  ``draws`` maps a kind of the family's own to its draw,
+    ``(key, shape, dtype) -> array``."""
     paths = sorted(lay)
+    draws = draws or {}
 
     def gen(key):
         keys = jax.random.split(key, len(paths))
-        return _nest({p: _draw(k, lay[p][0], lay[p][1], dtype)
+        return _nest({p: _draw(k, lay[p][0], lay[p][1], dtype, draws)
                       for p, k in zip(paths, keys)})
 
     return jax.jit(gen, out_shardings=shardings)(seed_key(seed))
 
 
-def check_layout(c, program_shapes) -> None:
-    """Raise unless the program expects exactly this tree of shapes."""
+def check_layout(lay, program_shapes) -> None:
+    """Raise unless the program expects exactly the tree of shapes of
+    layout ``lay``."""
     flat = {tuple(k.key for k in path): tuple(leaf.shape) for path, leaf
             in jax.tree_util.tree_flatten_with_path(program_shapes)[0]}
-    ours = {p: tuple(s) for p, (s, _) in layout(c).items()}
+    ours = {p: tuple(s) for p, (s, _) in lay.items()}
     if flat != ours:
         diff = sorted(set(flat.items()) ^ set(ours.items()))
         raise ValueError(f"weight layout differs from the program's: "
