@@ -13,7 +13,12 @@ written in ``bench/configs`` (Hugging Face key names):
 - :func:`position_stats`: the plain reference's logits per position;
 - :func:`decode_flops`, :func:`prefill_flops` and
   :func:`decode_step_work`: the required work the per-layer readers
-  hold device time against.
+  hold device time against;
+- :data:`CUTS` and :func:`min_layers`: the keys a configuration may cut,
+  by kind (``"depth"``, ``"experts"``, ``"vocab"``), and the fewest layers
+  a depth cut keeps (``harness.check_cut``);
+- ``SCOPES``, where the program names scopes of its own beyond
+  ``spans.SCOPES``: this family's program names none.
 """
 from __future__ import annotations
 
@@ -27,6 +32,15 @@ import numpy as np
 from reference import HIGHEST, Q_BLOCK, mm, rmsnorm, rope
 from weights import vocab_padded
 from work import BF16
+
+
+CUTS = {"depth": "num_hidden_layers", "vocab": "vocab_size"}
+
+
+def min_layers(c) -> int:
+    """Every layer is of one kind, so one layer is a whole period; a depth
+    cut keeps four."""
+    return 4
 
 
 def _dims(c):

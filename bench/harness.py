@@ -86,7 +86,10 @@ def load_family(c: dict, root: str = ROOT):
     under ``"family"``: ``bench/families/<family>.py`` under ``root``.
     It gives ``program_config``, ``layout`` (and ``DRAWS`` where it has
     kinds of weight of its own), ``position_stats``, ``decode_flops``,
-    ``prefill_flops`` and ``decode_step_work``.  There is no default."""
+    ``prefill_flops`` and ``decode_step_work``; ``CUTS`` and
+    ``min_layers`` where its configurations may be cut (:func:`check_cut`),
+    and ``SCOPES`` where its program names scopes of its own
+    (``bench/spans.py``).  There is no default."""
     where = os.path.join(root, "bench", "families")
     if "family" not in c:
         raise KeyError(f"configuration {c.get('name')!r} has no "
@@ -106,9 +109,66 @@ def load_family(c: dict, root: str = ROOT):
     return mod
 
 
+CUT_KINDS = ("depth", "experts", "vocab")
+
+
+def check_cut(c: dict, family) -> None:
+    """Raise ValueError, naming the key, unless configuration ``c`` is cut
+    only as the model-configs guide (section 4) allows.
+
+    Only the keys that ``family.CUTS`` names may be in ``reduced``: the
+    layers (``"depth"``), the routed experts held in each layer
+    (``"experts"``) and the vocabulary rows (``"vocab"``); widths are never
+    cut.  Each reduced key states its published value under
+    ``published``, above the value held, and a cut file states its
+    ``deployment``.  What is held keeps to the floors: at least
+    ``family.min_layers(c)`` layers, 8 experts (all where fewer are
+    published), an eighth of the vocabulary."""
+    reduced = c.get("reduced", [])
+    if not reduced:
+        return
+    name = c.get("name")
+    cuts = getattr(family, "CUTS", {})
+    unknown = {k: key for k, key in cuts.items() if k not in CUT_KINDS}
+    if unknown:
+        raise ValueError(f"family {c.get('family')!r} names cuts "
+                         f"{unknown}: a cut is one of {CUT_KINDS}; widths "
+                         f"are never cut")
+    kind = {key: k for k, key in cuts.items()}
+    if not c.get("deployment"):
+        raise ValueError(f"configuration {name!r} reduces {reduced} and "
+                         f"states no \"deployment\"")
+    published = c.get("published", {})
+    for key in reduced:
+        if key not in kind:
+            raise ValueError(
+                f"configuration {name!r} reduces {key!r}, which its "
+                f"family {c.get('family')!r} does not name in CUTS "
+                f"({cuts}): only the depth, the experts held and the "
+                f"vocabulary may be cut; widths are never cut")
+        if key not in published:
+            raise ValueError(f"configuration {name!r} reduces {key!r} and "
+                             f"states no published value for it under "
+                             f"\"published\"")
+        held, pub = c.get(key), published[key]
+        if not (isinstance(held, int) and isinstance(pub, int)
+                and held < pub):
+            raise ValueError(f"configuration {name!r} holds {key!r} = "
+                             f"{held!r} of a published {pub!r}: a cut "
+                             f"holds a whole number below the published one")
+        floor = (family.min_layers(c) if kind[key] == "depth"
+                 else min(8, pub) if kind[key] == "experts"
+                 else -(-pub // 8))
+        if held < floor:
+            raise ValueError(f"configuration {name!r} holds {key!r} = "
+                             f"{held} of a published {pub}: its "
+                             f"{kind[key]} cut keeps at least {floor}")
+
+
 def resolve(workload: str, root: str = ROOT) -> Cell:
     """The cell named ``workload`` with its configuration, mix, readers
-    and model family, found by name."""
+    and model family, found by name; the configuration's cuts are
+    checked (:func:`check_cut`)."""
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -118,6 +178,12 @@ def resolve(workload: str, root: str = ROOT) -> Cell:
     entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
     with open(os.path.join(root, entry["file"])) as f:
         config = json.load(f)
+    if entry["reduced"] != config.get("reduced", []):
+        raise ValueError(f"configuration {entry['name']!r}: BENCHMARK.json "
+                         f"reduces {entry['reduced']}, {entry['file']} "
+                         f"{config.get('reduced', [])}")
+    family = load_family(config, root)
+    check_cut(config, family)
     mix = traffic.load_mix(os.path.join(root, "bench", "mixes",
                                         w["traffic"] + ".json"))
     per_layer = [m for m in bench["per_layer"] if _applies(m, workload)]
@@ -125,7 +191,7 @@ def resolve(workload: str, root: str = ROOT) -> Cell:
                 [m for m in bench["end_to_end"] if _applies(m, workload)],
                 per_layer,
                 {m["name"]: load_reader(m["name"], root) for m in per_layer},
-                load_family(config, root))
+                family)
 
 
 # -------------------------------------------------------- the program

@@ -8,10 +8,11 @@ annotation ``repro.<track>.<name>`` (``repro.obs.tracer.Phase``), the
 positions the step needs) and ``kv_read_tokens`` (the positions its
 attention reads), and the regions of its jits are named scopes
 (``attn``, ``kv_write``, ``mlp``, ``head``) that reach each device
-operation's ``op_name``.  This module reads them from the xplane that
-``bench/run.py`` writes under ``bench_traces/<cell>``, once per run, for
-the readers in ``bench/metrics``.  A trace of a program that writes none
-of them gives empty lists, and the readers then return None.
+operation's ``op_name``; a model family may name more scopes of its
+own (its module's ``SCOPES``).  This module reads them from the xplane
+that ``bench/run.py`` writes under ``bench_traces/<cell>``, once per
+run, for the readers in ``bench/metrics``.  A trace of a program that
+writes none of them gives empty lists, and the readers then return None.
 
 On a TPU the ``op_name`` is the ``tf_op`` stat of each operation's
 event *metadata*, which ``jax.profiler.ProfileData`` does not expose;
@@ -58,11 +59,11 @@ def parse_name(name: str):
     return base, args
 
 
-def scope_of(op_name: str) -> Optional[str]:
-    """The innermost of the program's named scopes in an op's
-    ``op_name`` (``jit(f)/while/body/closed_call/attn/dot_general``)."""
+def scope_of(op_name: str, scopes=SCOPES) -> Optional[str]:
+    """The innermost of ``scopes`` in an op's ``op_name``
+    (``jit(f)/while/body/closed_call/attn/dot_general``)."""
     for part in reversed(op_name.split("/")):
-        if part in SCOPES:
+        if part in scopes:
             return part
     return None
 
@@ -143,10 +144,10 @@ def op_names(data: bytes) -> Dict[str, Dict[str, str]]:
     return out
 
 
-def load(path: str) -> Spans:
-    """The events ``trace.load`` reads, each op with its scope in
-    ``stats["scope"]`` where it has one, and the program's host spans
-    with their arguments in ``stats``."""
+def load(path: str, scopes=SCOPES) -> Spans:
+    """The events ``trace.load`` reads, each op with the innermost of
+    ``scopes`` in ``stats["scope"]`` where it has one, and the program's
+    host spans with their arguments in ``stats``."""
     from jax.profiler import ProfileData
     with open(path, "rb") as f:
         data = f.read()
@@ -157,7 +158,7 @@ def load(path: str) -> Spans:
         device = plane.name.startswith(T.DEVICE_PREFIX)
         if not device and plane.name != T.HOST_PLANE:
             continue
-        scopes: Dict[str, Optional[str]] = {}
+        found: Dict[str, Optional[str]] = {}
         for line in plane.lines:
             if device and line.name not in (T.OPS_LINE, T.MODULES_LINE):
                 continue
@@ -166,11 +167,12 @@ def load(path: str) -> Spans:
                 if device:
                     stats = {k: v for k, v in e.stats if k in keep}
                     if line.name == T.OPS_LINE:
-                        if name not in scopes:
-                            scopes[name] = scope_of(
-                                ops.get(plane.name, {}).get(name, ""))
-                        if scopes[name]:
-                            stats["scope"] = scopes[name]
+                        if name not in found:
+                            found[name] = scope_of(
+                                ops.get(plane.name, {}).get(name, ""),
+                                scopes)
+                        if found[name]:
+                            stats["scope"] = found[name]
                 elif name.startswith(T.ANNOTATION_PREFIX):
                     stats = {}
                 elif name.startswith(PROGRAM_PREFIX):
@@ -188,22 +190,25 @@ def load(path: str) -> Spans:
     return Spans(events, program)
 
 
-_CACHE: Dict[str, Spans] = {}
+_CACHE: Dict[tuple, Spans] = {}
 
 
 def of(run) -> Optional[Spans]:
     """The spans of ``run``'s trace (read once), or None for an untraced
-    run or one whose trace is gone."""
+    run or one whose trace is gone.  Ops are booked to :data:`SCOPES` and
+    to the scopes ``run``'s model family names (its ``SCOPES``)."""
     if run.trace is None:
         return None
     try:
         path = T.find_xplane(os.path.join(TRACES, run.cell))
     except FileNotFoundError:
         return None
-    if path not in _CACHE:
+    scopes = SCOPES + tuple(getattr(run.family, "SCOPES", ()))
+    key = (path, scopes)
+    if key not in _CACHE:
         _CACHE.clear()
-        _CACHE[path] = load(path)
-    return _CACHE[path]
+        _CACHE[key] = load(path, scopes)
+    return _CACHE[key]
 
 
 def window(sp: Spans):

@@ -1,9 +1,9 @@
 """A model family is found by the name its configuration gives: a
 family written into a checkout of its own, in new files only, is taken
-by every step of a run; a name with no file is an error; and the
-generic modules leave every architecture to the family."""
+by every step of a run, with a configuration cut as the family allows
+and the scopes the family names; a name with no file is an error; and
+the generic modules leave every architecture to the family."""
 import hashlib
-import json
 import os
 import re
 import time
@@ -14,15 +14,15 @@ import numpy as np
 import pytest
 
 import harness
+import spans
 import tiny
 import traffic
 import weights as W
 from peaks import PEAKS
-from tiny import BENCH, ROOT
+from tiny import BENCH, CELL
 
 G = harness.load_family(tiny.CONFIG)
 SEED = 2 ** 33 + 5
-CELL = "tiny-closed"
 
 # A family that computes as gqa_decoder does and draws its biases by a
 # kind of its own, as a family in new files would.
@@ -46,37 +46,34 @@ def layout(c):
             for p, (s, k) in _G.layout(c).items()}
 '''
 
+# The same, with a program that names an experts scope and configurations
+# that may be cut as a family with routed experts allows.
+CUT_FAMILY = FAMILY + '''
 
-def _checkout(tmp_path, family="tmp_family", source=FAMILY, config=None):
-    """A checkout that holds a copy of BENCHMARK.json with one more cell,
-    the tiny configuration naming ``family``, and that family's file;
-    the mixes and readers are the benchmark's own."""
-    root = tmp_path / "checkout"
-    (root / "bench" / "configs").mkdir(parents=True)
-    (root / "bench" / "families").mkdir()
-    for d in ("metrics", "mixes"):
-        os.symlink(os.path.join(BENCH, d), root / "bench" / d)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    bench["configs"].append({"name": "tiny", "source": "tests",
-                             "file": "bench/configs/tiny.json",
-                             "reduced": [], "why": "a family in new files"})
-    bench["workloads"].append({"name": CELL, "config": "tiny",
-                               "traffic": "offline", "chips": 1,
-                               "why": "the tiny configuration"})
-    for m in bench["per_layer"]:
-        if m["name"] in ("decode_roofline", "step_mfu"):
-            m["workloads"].append(CELL)
-    (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    c = config or tiny.config(family=family)
-    (root / "bench" / "configs" / "tiny.json").write_text(json.dumps(c))
-    if source is not None:
-        (root / "bench" / "families" / (family + ".py")).write_text(source)
-    return str(root)
+SCOPES = ("experts",)
+CUTS = dict(_G.CUTS, experts="n_routed_experts")
+min_layers = _G.min_layers
+'''
 
 
 def test_a_family_in_new_files_drives_a_whole_run(tmp_path, monkeypatch):
-    root = _checkout(tmp_path)
+    _whole_run(tmp_path, monkeypatch, FAMILY,
+               tiny.config(family="tmp_family"))
+
+
+def test_a_cut_configuration_and_family_scopes_drive_a_whole_run(
+        tmp_path, monkeypatch):
+    _whole_run(tmp_path, monkeypatch, CUT_FAMILY, tiny.config(
+        family="tmp_family", num_hidden_layers=4,
+        reduced=["num_hidden_layers"], published={"num_hidden_layers": 8},
+        deployment="two chips as two pipeline stages of four layers"))
+
+
+def _whole_run(tmp_path, monkeypatch, source, config):
+    """``resolve``, ``build``, a CPU drive, both work readers and
+    ``compare`` on a checkout that adds the family ``source`` and
+    ``config`` in new files only."""
+    root = tiny.checkout(tmp_path, config, {"tmp_family": source})
     cell = harness.resolve(CELL, root)
     F = cell.family
     assert F.__file__ == os.path.join(root, "bench", "families",
@@ -90,7 +87,8 @@ def test_a_family_in_new_files_drives_a_whole_run(tmp_path, monkeypatch):
     reqs = traffic.generate(mix, seconds=2.0, seed=SEED,
                             vocab_size=c["vocab_size"],
                             slots=c["max_batch"])
-    out = harness.drive(sys_, mix, reqs, 2.0, None)
+    traces = tmp_path / "traces"
+    out = harness.drive(sys_, mix, reqs, 2.0, str(traces / CELL))
     eng = sys_.engine
     run = harness.Run(CELL, c, F, mix, 1, PEAKS["TPU v5 lite"], out["t0"],
                       out["t1"], out["records"], out["ticks"],
@@ -100,6 +98,14 @@ def test_a_family_in_new_files_drives_a_whole_run(tmp_path, monkeypatch):
     assert ticks
     run.trace = {"programs": {"decode": {"calls": ticks,
                                          "device_s": 0.05 * ticks}}}
+    # the trace's ops are booked to the scopes the family names too
+    loaded = []
+    monkeypatch.setattr(spans, "TRACES", str(traces))
+    monkeypatch.setattr(spans, "load", lambda path, scopes: (
+        loaded.append(scopes), spans.Spans([], []))[1])
+    assert spans.of(run) is not None
+    assert loaded == [spans.SCOPES + getattr(F, "SCOPES", ())]
+    monkeypatch.undo()
     calls = []
     for name in ("decode_step_work", "decode_flops", "prefill_flops"):
         fn = getattr(F, name)
@@ -125,14 +131,14 @@ def test_a_family_in_new_files_drives_a_whole_run(tmp_path, monkeypatch):
 @pytest.mark.parametrize("case", ["no_file", "no_key"])
 def test_a_family_that_cannot_be_found_is_an_error(tmp_path, case):
     if case == "no_file":
-        root = _checkout(tmp_path, family="no_such_family", source=None)
+        root = tiny.checkout(tmp_path, tiny.config(family="no_such_family"))
         want = os.path.join(root, "bench", "families", "no_such_family.py")
         with pytest.raises(FileNotFoundError, match=re.escape(want)):
             harness.resolve(CELL, root)
     else:
         c = tiny.config()
         del c["family"]
-        root = _checkout(tmp_path, config=c)
+        root = tiny.checkout(tmp_path, c)
         want = os.path.join(root, "bench", "families", "<family>.py")
         with pytest.raises(KeyError, match=re.escape(want)):
             harness.resolve(CELL, root)

@@ -3,6 +3,7 @@ by ``repro.*`` phases, leaf-op time per named scope, the three readers
 on synthetic traces, and a recorded CPU trace of the engine."""
 import dataclasses
 import re
+import types
 
 import pytest
 
@@ -136,6 +137,7 @@ class FakeRun:
     cell: str = "tiny"
     trace: dict = dataclasses.field(default_factory=dict)
     notes: dict = dataclasses.field(default_factory=dict)
+    family: object = None
 
 
 @pytest.mark.parametrize("name,value", [
@@ -243,6 +245,10 @@ def test_scopes_in_the_compiled_decode_program():
     assert spans.scope_of("jit(f)/while/body/closed_call/attn/"
                           "flash_decode_kernel_scope/sub:") == "attn"
     assert spans.scope_of("jit(f)/while/body/add:") is None
+    # a scope a family names wins inside the program's own
+    experts = "jit(f)/while/body/closed_call/mlp/experts/dot_general:"
+    assert spans.scope_of(experts) == "mlp"
+    assert spans.scope_of(experts, spans.SCOPES + ("experts",)) == "experts"
 
 
 def _varint(n):
@@ -289,3 +295,49 @@ def test_op_names_read_from_the_event_metadata():
     assert spans.op_names(space) == {"/device:TPU:0": {
         "%fusion.1 = f32[2] fusion()": "jit(f)/head/argmax:",
         "%copy.3 = f32[2] copy()": "jit(f)/while/body/attn/gather:"}}
+
+
+def _decode_xspace():
+    """A serialized XSpace of one chip: one decode call of 30 ms holding
+    an attention gather (10 ms), a routed-expert matmul inside the MLP
+    (6 ms) and a shared MLP matmul (4 ms), each named by its ``tf_op``."""
+    ops = {1: ("jit__fused_paged(7)", None),
+           2: ("%fusion.1 = bf16[2] fusion()", "attn/gather:"),
+           3: ("%fusion.2 = bf16[2] fusion()", "mlp/experts/dot_general:"),
+           4: ("%fusion.3 = bf16[2] fusion()", "mlp/dot_general:")}
+    ev_md = [(4, _msg((1, i), (2, _msg((1, i), (2, name), *(
+        [(5, _msg((1, 7), (5, "jit(_fused_paged)/while/body/" + op)))]
+        if op else [])))))
+        for i, (name, op) in ops.items()]
+    stat_md = (5, _msg((1, 7), (2, _msg((1, 7), (2, "tf_op")))))
+
+    def ev(md, start_ms, dur_ms):
+        return (4, _msg((1, md), (2, int(start_ms * 1e9)),
+                        (3, int(dur_ms * 1e9))))
+
+    modules = (3, _msg((1, 1), (2, T.MODULES_LINE), (3, 1000), ev(1, 0, 30)))
+    op_line = (3, _msg((1, 2), (2, T.OPS_LINE), (3, 1000), ev(2, 0, 10),
+                       ev(3, 10, 6), ev(4, 16, 4)))
+    return _msg((1, _msg((1, 1), (2, DEV0), modules, op_line, *ev_md,
+                         stat_md)))
+
+
+def test_a_family_s_scopes_book_its_ops_in_decode_scope_ms(tmp_path,
+                                                            monkeypatch):
+    """One trace read for the GQA family (no scopes of its own) and for a
+    family that names ``experts``: the expert matmul leaves ``mlp`` for
+    ``experts`` only in the second, and the GQA family's readings are
+    those of the program's scopes alone, before and after."""
+    d = tmp_path / "tiny" / "plugins" / "profile" / "run"
+    d.mkdir(parents=True)
+    (d / "chip.xplane.pb").write_bytes(_decode_xspace())
+    monkeypatch.setattr(spans, "TRACES", str(tmp_path))
+    read = harness.load_reader("decode_attn_ms")
+    gqa = harness.load_family({"family": "gqa_decoder"})
+    moe = types.SimpleNamespace(SCOPES=("experts",))
+    for family, want in ((gqa, {"attn": 10.0, "mlp": 10.0}),
+                         (moe, {"attn": 10.0, "experts": 6.0, "mlp": 4.0}),
+                         (gqa, {"attn": 10.0, "mlp": 10.0})):
+        run = FakeRun(family=family)
+        assert read(run) == pytest.approx(10.0)
+        assert run.notes["decode_scope_ms"] == pytest.approx(want)

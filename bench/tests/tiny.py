@@ -1,6 +1,8 @@
 """A tiny configuration and mixes that drive the harness on the CPU,
-and where the benchmark lives."""
+where the benchmark lives, and a checkout that adds a cell of its own."""
 import copy
+import glob
+import json
 import os
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -52,3 +54,39 @@ def config(**kw):
     c = copy.deepcopy(CONFIG)
     c.update(kw)
     return c
+
+
+CELL = "tiny-closed"
+
+
+def checkout(tmp_path, config, families=None):
+    """A checkout that holds a copy of BENCHMARK.json with one more cell,
+    :data:`CELL`, of ``config`` (written as ``bench/configs/tiny.json``)
+    under the offline mix, which ``decode_roofline`` and ``step_mfu``
+    read; the benchmark's own families beside ``families``
+    (``{name: source}``), and its own mixes and readers."""
+    root = tmp_path / "checkout"
+    (root / "bench" / "configs").mkdir(parents=True)
+    (root / "bench" / "families").mkdir()
+    for d in ("metrics", "mixes"):
+        os.symlink(os.path.join(BENCH, d), root / "bench" / d)
+    for path in glob.glob(os.path.join(BENCH, "families", "*.py")):
+        os.symlink(path, root / "bench" / "families" /
+                   os.path.basename(path))
+    for name, source in (families or {}).items():
+        (root / "bench" / "families" / (name + ".py")).write_text(source)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": "tiny", "source": "tests",
+                             "file": "bench/configs/tiny.json",
+                             "reduced": config.get("reduced", []),
+                             "why": "a configuration in new files"})
+    bench["workloads"].append({"name": CELL, "config": "tiny",
+                               "traffic": "offline", "chips": 1,
+                               "why": "the tiny configuration"})
+    for m in bench["per_layer"]:
+        if m["name"] in ("decode_roofline", "step_mfu"):
+            m["workloads"].append(CELL)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    (root / "bench" / "configs" / "tiny.json").write_text(json.dumps(config))
+    return str(root)
